@@ -26,7 +26,7 @@ endif
 	fi
 FORCE:
 
-.PHONY: test test-slow test-sharded test-compiled lint bench-smoke bench \
+.PHONY: test test-slow test-sharded lint bench-smoke bench \
 	report-gate bench-gate dev-deps
 
 test:            ## tier-1 test suite (the verify gate for every PR; excludes slow-marked tests)
@@ -43,14 +43,6 @@ test-sharded:    ## superstep differential + sharding tests under 8 faked host d
 
 test-slow:       ## pixel-path + hypothesis-heavy tests (nightly-blocking, per-PR non-blocking CI job)
 	$(PY) -m pytest -q -m slow
-
-# Pixel-path tests with the interpret knob OFF: on a TPU runtime this
-# exercises the real compiled Pallas lowering; on plain CPU (the GitHub
-# runner) the launching tests skip cleanly via the compiled_available()
-# probe and only the backend-free ones run — a green-but-skipped run here
-# is expected, a FAILED one means the compiled path or the probe broke.
-test-compiled:   ## pixel-cascade tests under REPRO_PALLAS_INTERPRET=0 (compiled Pallas where the backend lowers it)
-	REPRO_PALLAS_INTERPRET=0 $(PY) -m pytest -x -q -rs tests/test_pixel_cascade.py
 
 lint:            ## ruff check (CI blocks on this; skipped when ruff is absent)
 	@if $(PY) -m ruff --version >/dev/null 2>&1; then \

@@ -1,8 +1,9 @@
-"""Kernel microbenchmarks: Pallas (interpret on CPU) vs pure-jnp oracle.
+"""Kernel microbenchmarks: Pallas kernels vs their pure-jnp oracles.
 
-On this CPU host the interesting number is the *oracle* timing (the Pallas
-path interprets the kernel body in Python and is not representative of TPU
-throughput); both are reported, with bytes-based derived throughput.
+The backend decides what the Pallas rows are: compiled kernels on a TPU,
+the Pallas interpreter on a CPU (``repro.kernels.runtime``).  Row names
+carry that substrate, so an interpreted or CPU timing never appears under
+a ``*_compiled`` name; the oracle rows are XLA on whatever backend ran.
 """
 from __future__ import annotations
 
@@ -15,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
-from repro.kernels.runtime import compiled_available, interpret_default
+from repro.kernels.runtime import resolve_interpret
 from repro.launch.roofline import pixel_cascade_roofline
 from repro.system import PixelFrontend, pixel_city, synthetic_confidence_stream
 from repro.system.superstep import _superstep_fn
@@ -34,6 +35,11 @@ BENCH_PIXEL_CASCADE = os.path.join(os.path.dirname(__file__),
 PIXEL_SHAPES = ((4, 96, 128), (16, 96, 128), (4, 192, 256), (16, 192, 256))
 
 
+def _pallas_mode() -> str:
+    """``compiled`` on a TPU backend, ``interpret`` on the CPU one."""
+    return "interpret" if resolve_interpret(None) else "compiled"
+
+
 def _time(fn, *args, n=10, **kw):
     fn(*args, **kw)
     r = fn(*args, **kw)
@@ -50,27 +56,23 @@ def _time(fn, *args, n=10, **kw):
 def pixel_cascade_bench(verbose: bool = True, out_path: str = None):
     """Fused-vs-staged pixel frontend across frame sizes and fleet widths.
 
-    Three row families per (B, H, W):
+    Three row families per (B, H, W), with ``<mode>`` the backend's Pallas
+    mode (``compiled`` on TPU, ``interpret`` on CPU):
 
-      * ``staged_interpret`` — the original three Pallas launches
-        (framediff, dilate, erode) + jnp mask reduction, under the
-        repo-wide interpret knob.  This is the ISSUE's baseline.
-      * ``fused_interpret`` — ONE fused band-pipelined launch, same knob.
-      * ``fused_compiled`` — the fused chain compiled: the real compiled
-        Pallas kernel where the backend can lower it
-        (``REPRO_PALLAS_INTERPRET=0`` on TPU), else the XLA-jitted
-        reference twin of the same fused math (substrate ``xla_ref``) —
-        the substrate is recorded per row so interpreted numbers are
-        never presented as compiled ones.
+      * ``staged_<mode>`` — the original three Pallas launches
+        (framediff, dilate, erode) + jnp mask reduction.
+      * ``fused_<mode>`` — ONE fused band-pipelined launch.
+      * ``fused_xla_ref`` — the XLA-jitted reference twin of the same
+        fused math (substrate ``xla_ref``, no Pallas launch).
 
     Each shape also records the analytic per-kernel roofline fractions
     (``launch/roofline.pixel_cascade_roofline``): same FLOPs by
     construction, the staged chain paying 4 extra full-frame HBM passes.
-    Writes the committed ``BENCH_pixel_cascade.json`` snapshot.
+    The payload names the device it ran on.  Writes the committed
+    ``BENCH_pixel_cascade.json`` snapshot.
     """
-    compiled = compiled_available()
-    interp = interpret_default()
-    pallas_sub = "pallas_interpret" if interp else "pallas_compiled"
+    mode = _pallas_mode()
+    pallas_sub = f"pallas_{mode}"
     shapes = {}
     for (B, H, W) in PIXEL_SHAPES:
         f = [jax.random.randint(jax.random.PRNGKey(40 + i), (B, H, W, 3),
@@ -81,13 +83,8 @@ def pixel_cascade_bench(verbose: bool = True, out_path: str = None):
         us_staged = _time(ops.pixel_cascade, *f, threshold=40, fused=False,
                           n=n)
         us_fused = _time(ops.pixel_cascade, *f, threshold=40, n=n)
-        if compiled and not interp:
-            us_fc, sub_fc = us_fused, "pallas_compiled"
-        else:
-            # no compiled Pallas on this backend: XLA-jit the fused math
-            us_fc = _time(ops.pixel_cascade, *f, threshold=40,
-                          use_pallas=False, n=n)
-            sub_fc = "xla_ref"
+        us_ref = _time(ops.pixel_cascade, *f, threshold=40,
+                       use_pallas=False, n=n)
 
         def _row(us, substrate, launches):
             return {"us_per_call": round(us, 1),
@@ -97,10 +94,9 @@ def pixel_cascade_bench(verbose: bool = True, out_path: str = None):
         key = f"B{B}_{H}x{W}"
         shapes[key] = {
             "rows": {
-                "staged_interpret": _row(us_staged, pallas_sub, 3),
-                "fused_interpret": _row(us_fused, pallas_sub, 1),
-                "fused_compiled": _row(us_fc, sub_fc,
-                                       1 if sub_fc != "xla_ref" else 0),
+                f"staged_{mode}": _row(us_staged, pallas_sub, 3),
+                f"fused_{mode}": _row(us_fused, pallas_sub, 1),
+                "fused_xla_ref": _row(us_ref, "xla_ref", 0),
             },
             "roofline": {
                 "fused": pixel_cascade_roofline(B, H, W, fused=True).to_row(),
@@ -108,22 +104,22 @@ def pixel_cascade_bench(verbose: bool = True, out_path: str = None):
                                                  fused=False).to_row(),
             },
             "derived": {
-                "fused_vs_staged_interpret_speedup": round(
-                    us_staged / us_fused, 2),
-                "fused_compiled_vs_staged_interpret_speedup": round(
-                    us_staged / us_fc, 2),
+                "fused_vs_staged_speedup": round(us_staged / us_fused, 2),
+                "xla_ref_vs_staged_speedup": round(us_staged / us_ref, 2),
                 "launch_reduction": 3,
             },
         }
         if verbose:
             d = shapes[key]["derived"]
-            print(f"pixel cascade {key}: staged {us_staged:.0f} us (3 "
-                  f"launches) vs fused {us_fused:.0f} us (1 launch) -> "
-                  f"{d['fused_vs_staged_interpret_speedup']}x; compiled "
-                  f"[{sub_fc}] {us_fc:.0f} us -> "
-                  f"{d['fused_compiled_vs_staged_interpret_speedup']}x")
-    payload = {"pallas_compiled_available": compiled,
-               "interpret_knob": interp, "shapes": shapes}
+            print(f"pixel cascade {key} [{pallas_sub}]: staged "
+                  f"{us_staged:.0f} us (3 launches) vs fused "
+                  f"{us_fused:.0f} us (1 launch) -> "
+                  f"{d['fused_vs_staged_speedup']}x; xla_ref "
+                  f"{us_ref:.0f} us -> {d['xla_ref_vs_staged_speedup']}x")
+    dev = jax.devices()[0]
+    payload = {"device": {"platform": dev.platform, "kind": dev.device_kind,
+                          "count": jax.device_count()},
+               "shapes": shapes}
     with open(out_path or BENCH_PIXEL_CASCADE, "w") as fh:
         json.dump(payload, fh, indent=2)
         fh.write("\n")
@@ -131,6 +127,7 @@ def pixel_cascade_bench(verbose: bool = True, out_path: str = None):
 
 
 def run(verbose: bool = True):
+    pallas = f"pallas_{_pallas_mode()}"
     B, H, W = 4, 96, 128
     f = [jax.random.randint(jax.random.PRNGKey(i), (B, H, W, 3), 0, 256)
          for i in range(3)]
@@ -140,18 +137,19 @@ def run(verbose: bool = True):
     bytes_fd = 3 * B * H * W * 3 * 4
     rows.append(("framediff_ref", _time(ops.framediff, *f, threshold=40,
                                         use_pallas=False), bytes_fd))
-    rows.append(("framediff_pallas_interp", _time(ops.framediff, *f,
+    rows.append((f"framediff_{pallas}", _time(ops.framediff, *f,
                                                   threshold=40), bytes_fd))
     bytes_mo = B * H * W * 4 * 2
     rows.append(("dilate3x3_ref", _time(ops.dilate3x3, mask,
                                         use_pallas=False), bytes_mo))
-    rows.append(("dilate3x3_pallas_interp", _time(ops.dilate3x3, mask), bytes_mo))
+    rows.append((f"dilate3x3_{pallas}", _time(ops.dilate3x3, mask),
+                 bytes_mo))
     rows.append(("erode3x3_ref", _time(ops.erode3x3, mask,
                                        use_pallas=False), bytes_mo))
     bytes_tr = 4096 * 4 * 3
     rows.append(("triage_ref", _time(ops.triage, conf, alpha=0.8, beta=0.1,
                                      capacity=512, use_pallas=False), bytes_tr))
-    rows.append(("triage_pallas_interp", _time(ops.triage, conf, alpha=0.8,
+    rows.append((f"triage_{pallas}", _time(ops.triage, conf, alpha=0.8,
                                                beta=0.1, capacity=512), bytes_tr))
     # fleet triage: the whole city_scale tick (64 edges x 512-wide bucket)
     # in ONE launch — vs 64 per-edge launches per tick before
@@ -163,7 +161,7 @@ def run(verbose: bool = True):
     rows.append(("triage_fleet_ref",
                  _time(ops.triage_fleet, fleet_conf, fleet_th, capacity=64,
                        use_pallas=False), bytes_fleet))
-    rows.append(("triage_fleet_pallas_interp",
+    rows.append((f"triage_fleet_{pallas}",
                  _time(ops.triage_fleet, fleet_conf, fleet_th, capacity=64),
                  bytes_fleet))
     # multi-query fleet: 3 live CQs x 64 edges x 512-wide buckets, the
@@ -176,7 +174,7 @@ def run(verbose: bool = True):
     rows.append(("triage_fleet_qen_ref",
                  _time(ops.triage_fleet, mq_conf, mq_th, capacity=64,
                        use_pallas=False), bytes_mq))
-    rows.append(("triage_fleet_qen_pallas_interp",
+    rows.append((f"triage_fleet_qen_{pallas}",
                  _time(ops.triage_fleet, mq_conf, mq_th, capacity=64),
                  bytes_mq))
     # fleet recalibration: one fused (E, N) Platt-fit launch per update
@@ -191,7 +189,7 @@ def run(verbose: bool = True):
     rows.append(("calibrate_fleet_ref",
                  _time(ops.calibrate_fleet, cal_s, cal_y,
                        use_pallas=False, n=3), bytes_cal))
-    rows.append(("calibrate_fleet_pallas_interp",
+    rows.append((f"calibrate_fleet_{pallas}",
                  _time(ops.calibrate_fleet, cal_s, cal_y, n=3), bytes_cal))
     # cross-camera track association: ONE fused (M, K) QK-similarity +
     # greedy-assignment launch matches a tick's crops (all edges) against
@@ -209,7 +207,7 @@ def run(verbose: bool = True):
     rows.append(("associate_ref",
                  _time(ops.associate_tracks, a_emb, a_trk, a_cq, a_tq,
                        a_thr, use_pallas=False, n=5), bytes_as))
-    rows.append(("associate_pallas_interp",
+    rows.append((f"associate_{pallas}",
                  _time(ops.associate_tracks, a_emb, a_trk, a_cq, a_tq,
                        a_thr, n=5), bytes_as))
     # flash attention (small shape; interpret mode on CPU)
@@ -219,7 +217,7 @@ def run(verbose: bool = True):
     bytes_fl = (qk.size + kk.size + vk.size) * 4
     rows.append(("flash_attn_ref", _time(ops.flash_attention, qk, kk, vk,
                                          use_pallas=False, n=5), bytes_fl))
-    rows.append(("flash_attn_pallas_interp",
+    rows.append((f"flash_attn_{pallas}",
                  _time(ops.flash_attention, qk, kk, vk, n=5), bytes_fl))
     out = {}
     for name, us, nbytes in rows:

@@ -45,6 +45,7 @@ import sys
 
 sys.path.insert(0, "src")
 
+from repro.kernels.runtime import enable_compile_cache  # noqa: E402
 from repro.system import (  # noqa: E402
     SCENARIOS,
     SCHEMES,
@@ -74,6 +75,11 @@ SMOKE_OVERRIDES = {
     "vehicle_pursuit": dict(cameras=12, duration=60.0),
     "crowd_flow": dict(cameras=8, duration=45.0),
 }
+
+#: the ``--cameras`` / ``--duration`` that ``make bench-smoke`` passes when
+#: it (re)builds the committed ``reports/`` baselines
+BASELINE_CAMERAS = 4
+BASELINE_DURATION = 30.0
 
 
 def check_consistency(name: str, scheme: str, summary: dict) -> None:
@@ -158,10 +164,13 @@ def compact_query_row(row: dict) -> dict:
 
 
 def run_scenario(name: str, frontend_name: str, cameras: int,
-                 duration: float, seed: int, json_out: str = None) -> None:
+                 duration: float, seed: int, json_out: str = None,
+                 **scenario_kw) -> dict:
     """Simulate one scenario under every scheme (+ ablation rows); print
-    the table and optionally write/validate its JSON artifact."""
-    sc = SCENARIOS[name](num_cameras=cameras, duration_s=duration, seed=seed)
+    the table, optionally write/validate its JSON artifact, and return the
+    report document.  ``scenario_kw`` goes to the preset factory."""
+    sc = SCENARIOS[name](num_cameras=cameras, duration_s=duration, seed=seed,
+                         **scenario_kw)
     frontend = PixelFrontend(seed=seed) if frontend_name == "pixel" else None
     if frontend is not None:
         stream = frontend.stream(sc)         # cached across the scheme sweep
@@ -237,16 +246,28 @@ def run_scenario(name: str, frontend_name: str, cameras: int,
                       f"  train {row.get('train_s', 0.0):6.2f}s"
                       f"  deferred {row.get('deferred', 0):4d}"
                       f"  n {row['n_items']}")
+    doc = {"scenario": name, "frontend": frontend_name,
+           "n_detections": len(stream), "num_edges": sc.num_edges,
+           "schemes": per_scheme}
     if json_out:
         os.makedirs(json_out, exist_ok=True)
         path = os.path.join(json_out, f"{name}-{frontend_name}.json")
         with open(path, "w") as fh:
-            json.dump({"scenario": name, "frontend": frontend_name,
-                       "n_detections": len(stream),
-                       "num_edges": sc.num_edges,
-                       "schemes": per_scheme}, fh, indent=2)
+            json.dump(doc, fh, indent=2)
         load_report(path)            # round-trip the consistency gate
         print(f"   -> {path}")
+    return doc
+
+
+def smoke_args(name: str, frontend: str = "confidence",
+               cameras: int = BASELINE_CAMERAS,
+               duration: float = BASELINE_DURATION):
+    """(frontend, cameras, duration) of a preset's ``--scenario all`` run:
+    its ``SMOKE_OVERRIDES`` entry over the given CLI values.  The defaults
+    are the values ``make bench-smoke`` passes, which built ``reports/``."""
+    ov = SMOKE_OVERRIDES.get(name, {})
+    return (ov.get("frontend", frontend), ov.get("cameras", cameras),
+            ov.get("duration", duration))
 
 
 def main():
@@ -267,16 +288,14 @@ def main():
     ap.add_argument("--duration", type=float, default=60.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.scenario == "all":
         # every preset, one process: per-scenario frontend + smoke-sized
         # overrides from SMOKE_OVERRIDES, CLI values as the fallback
         for name in sorted(SCENARIOS):
-            ov = SMOKE_OVERRIDES.get(name, {})
-            run_scenario(name,
-                         ov.get("frontend", args.frontend),
-                         ov.get("cameras", args.cameras),
-                         ov.get("duration", args.duration),
+            run_scenario(name, *smoke_args(name, args.frontend, args.cameras,
+                                           args.duration),
                          args.seed, args.json_out)
         return
     if args.scenario:

@@ -9,12 +9,17 @@ same table the kernels will actually pad to and raise a clear
 ``ValueError`` *before* an oversized (Q·E, N) launch surfaces as an
 opaque Pallas block-shape error deep inside a run.
 
-Limits are sized for the CPU interpret-mode substrate this container
-runs: the fused triage kernel is a single block, so every padded element
-is materialized at once.  ``MAX_FLEET_ROWS`` bounds the per-tick folded
-(Q·E) row space a scenario may declare; ``MAX_SUPERSTEP_ELEMS`` bounds
-one scan superstep's folded (S·R, N) slab (the superstep planner clamps
-its tick span to stay under it, never errors).
+Limits are sized for one TPU v5e (16 GiB of HBM, 16 MiB of scoped VMEM
+per kernel).  VMEM bounds none of them: the fleet triage kernel grids its
+rows and walks its lanes in blocks of at most ``triage.BLOCK_ELEMS``, and
+the pixel cascade holds one (BAND_H, W') band of each plane at a time.
+What they bound is the slab in HBM and the bytes each launch moves
+between host and device.  ``MAX_FLEET_ROWS`` bounds the per-tick folded
+(Q·E) row space a scenario may declare: 2**17 rows of a 512-lane bucket
+are 256 MiB per f32/i32 operand.  ``MAX_SUPERSTEP_ELEMS`` bounds one scan
+superstep's folded (S·R, N) slab: 16 MiB per operand, about 48 MiB up
+and back per superstep (the superstep planner clamps its tick span to
+stay under it, never errors).
 """
 from __future__ import annotations
 
@@ -45,7 +50,7 @@ FRAME_LANE_W = 128
 MIN_FRAME_SIDE = 16
 
 #: largest padded per-camera pixel count (H_pad * W_pad) of one frame —
-#: bounds the interpret-mode slab like ``MAX_FLEET_ROWS`` bounds triage
+#: a camera's planar int32 frame triple is then at most 144 MiB of HBM
 MAX_FRAME_ELEMS = 1 << 22
 
 

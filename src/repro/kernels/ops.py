@@ -1,11 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels (padding, dtype, dispatch).
 
-Interpret mode is a single repo-wide switch (``repro.kernels.runtime``,
-env knob ``REPRO_PALLAS_INTERPRET``): it defaults ON because this
-container is CPU-only; on a real TPU runtime ``REPRO_PALLAS_INTERPRET=0``
-flips every launch in the repo to compiled — no per-kernel defaults to
-chase.  The wrappers here never pass ``interpret`` explicitly; each
-launcher resolves the knob itself.
+The wrappers never pass ``interpret``: each launcher asks
+``repro.kernels.runtime.resolve_interpret``, which compiles on a TPU
+backend and interprets on the CPU one.
 """
 from __future__ import annotations
 
@@ -25,7 +22,6 @@ from repro.kernels import pixel_cascade as _pc
 from repro.kernels import similarity as _sim
 from repro.kernels import triage as _tr
 from repro.kernels import ref as _ref
-from repro.kernels.runtime import interpret_default  # noqa: F401  (re-export)
 
 
 def _pad_hw(x: jax.Array, mh: int, mw: int, value=0) -> Tuple[jax.Array, int, int]:
@@ -89,16 +85,16 @@ def pixel_cascade(f0: jax.Array, f1: jax.Array, f2: jax.Array, *,
     mask reduction — retained as the differential reference the fused
     kernel is tested bit-exact against.  Frames are zero-padded to the
     (FRAME_BAND_H, FRAME_LANE_W) tile from ``kernels/buckets.py`` before
-    the fused launch; the pad is sliced back off and never reaches counts.
+    the fused launch, with the colour channels moved to a leading plane
+    axis; the pad is sliced back off and never reaches counts.
     """
     f0, f1, f2 = (x.astype(jnp.int32) for x in (f0, f1, f2))
     if use_pallas and fused:
         H, W = f0.shape[1], f0.shape[2]
-        f0p, f1p, f2p = (_pc.pad_frames(x) for x in (f0, f1, f2))
-        mask, band_counts = _pc._cascade_call(
-            f0p, f1p, f2p, threshold=threshold, maxval=maxval,
-            true_hw=(H, W))
-        return mask[:, :H, :W], band_counts.sum(axis=1)
+        mask, counts = _pc.pixel_cascade_pallas(
+            *(_pc.planar_frames(x) for x in (f0, f1, f2)),
+            threshold=threshold, maxval=maxval, true_hw=(H, W))
+        return mask[:, :H, :W], counts
     if not use_pallas:
         mask = _ref.pixel_cascade_ref(f0, f1, f2, threshold, maxval)
     else:

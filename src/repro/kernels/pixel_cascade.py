@@ -31,10 +31,15 @@ Band layout per grid step ``(b, i)`` of the ``(B, nb + 1)`` grid::
       │ band i     │─ first 2 rows ┘       │              │
       └────────────┘ ◀─ framediff(band i)  └──────────────┘
 
-The second output is the per-band foreground count — the mask reduction
-the host needs to skip connected-component labeling for motionless
-cameras (and the whole CCL fixpoint for motionless ticks) without paying
-another device pass over the mask.
+Frames arrive planar, (B, 3, H', W'), so a block's lane axis is the
+padded frame width and never the 3 colour channels.
+
+The second output is the per-camera foreground count — the mask
+reduction the host needs to skip connected-component labeling for
+motionless cameras (and the whole CCL fixpoint for motionless ticks)
+without paying another device pass over the mask.  Its (1, 1, 1) block
+is the whole trailing extent of a (B, 1, 1) array, which keeps it
+lane-legal, and it stays resident while the camera's bands accumulate.
 
 Boundary semantics match the staged chain bit-exactly: framediff outside
 the true (H, W) image is 0 (dilate's fill), dilated values outside it are
@@ -43,8 +48,9 @@ image so the pad region can never contribute to a count.  The stencil
 math itself is ``morphology.stencil3x3`` — the same nine-shift reduction
 the staged kernels run, one implementation for both paths.
 
-Target: TPU (compiled); validated on CPU with interpret=True against the
-staged kernels and the independent NumPy oracle ``ref.pixel_cascade_np``.
+Compiled on TPU, interpreted on CPU (``runtime.resolve_interpret``);
+validated against the staged kernels and the independent NumPy oracle
+``ref.pixel_cascade_np``.
 """
 from __future__ import annotations
 
@@ -64,11 +70,11 @@ BAND_H = FRAME_BAND_H
 
 
 def _framediff_band(f0, f1, f2, *, threshold: int, maxval: int) -> jax.Array:
-    """Eqs. 1-4 on one (bh, W, 3) frame band -> (bh, W) binary mask."""
+    """Eqs. 1-4 on one planar (3, bh, W) frame band -> (bh, W) binary mask."""
     d1 = jnp.abs(f1 - f0)                        # Eq. 1
     d2 = jnp.abs(f2 - f1)                        # Eq. 2
     da = jnp.bitwise_and(d1, d2)                 # Eq. 3 (uint8 bits in i32)
-    gray = (da[..., 0] * 299 + da[..., 1] * 587 + da[..., 2] * 114) // 1000
+    gray = (da[0] * 299 + da[1] * 587 + da[2] * 114) // 1000
     return jnp.where(gray > threshold, maxval, 0).astype(jnp.int32)
 
 
@@ -117,37 +123,39 @@ def _cascade_kernel(f0_ref, f1_ref, f2_ref, mask_ref, count_ref, fd, *,
         ocols = jax.lax.broadcasted_iota(jnp.int32, (bh, Wp), 1)
         out = jnp.where((orows < true_h) & (ocols < true_w), ero, 0)
         mask_ref[0] = out.astype(mask_ref.dtype)
-        count_ref[0, 0] = jnp.sum((out > 0).astype(jnp.int32))
+
+        # the camera's count block stays resident across its bands: zero it
+        # on the first drained band, accumulate on every band after
+        @pl.when(i == 1)
+        def _():
+            count_ref[...] = jnp.zeros_like(count_ref)
+
+        count_ref[...] += jnp.sum((out > 0).astype(jnp.int32),
+                                  keepdims=True)[None]
 
 
 def pixel_cascade_pallas(f0: jax.Array, f1: jax.Array, f2: jax.Array, *,
-                         threshold: int, maxval: int = 255,
+                         threshold: int, maxval: int,
+                         true_hw: Tuple[int, int],
                          interpret: Optional[bool] = None
                          ) -> Tuple[jax.Array, jax.Array]:
-    """(B, H', W', 3) int32 frame triple -> ((B, H', W') mask, (B, nb) counts).
+    """Planar (B, 3, H', W') int32 frame triple (``planar_frames``) ->
+    ((B, H', W') mask, (B,) foreground counts).
 
-    H' must be a multiple of BAND_H and W' of FRAME_LANE_W (ops.py pads
-    with zeros and passes the true (H, W) through ``true_hw``); the mask
-    is zero outside the true image and the per-band counts cover true
-    pixels only.
+    H' must be a multiple of BAND_H and W' of FRAME_LANE_W; the true
+    (H, W) comes in through ``true_hw``.  The mask is zero outside the
+    true image and the counts cover true pixels only.
     """
-    return _cascade_call(f0, f1, f2, threshold=threshold, maxval=maxval,
-                         true_hw=(f0.shape[1], f0.shape[2]),
-                         interpret=interpret)
-
-
-def _cascade_call(f0, f1, f2, *, threshold, maxval, true_hw,
-                  interpret=None):
     interpret = resolve_interpret(interpret)
-    B, Hp, Wp, C = f0.shape
+    B, C, Hp, Wp = f0.shape
     true_h, true_w = true_hw
     assert C == 3 and Hp % BAND_H == 0 and Wp % FRAME_LANE_W == 0, (f0.shape,)
     nb = Hp // BAND_H
     kernel = functools.partial(_cascade_kernel, nb=nb, true_h=true_h,
                                true_w=true_w, threshold=threshold,
                                maxval=maxval)
-    in_spec = pl.BlockSpec((1, BAND_H, Wp, 3),
-                           lambda b, i: (b, jnp.minimum(i, nb - 1), 0, 0))
+    in_spec = pl.BlockSpec((1, 3, BAND_H, Wp),
+                           lambda b, i: (b, 0, jnp.minimum(i, nb - 1), 0))
     mask, counts = pl.pallas_call(
         kernel,
         grid=(B, nb + 1),
@@ -155,27 +163,30 @@ def _cascade_call(f0, f1, f2, *, threshold, maxval, true_hw,
         out_specs=[
             pl.BlockSpec((1, BAND_H, Wp),
                          lambda b, i: (b, jnp.maximum(i - 1, 0), 0)),
-            pl.BlockSpec((1, 1), lambda b, i: (b, jnp.maximum(i - 1, 0))),
+            pl.BlockSpec((1, 1, 1), lambda b, i: (b, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hp, Wp), f0.dtype),
-            jax.ShapeDtypeStruct((B, nb), jnp.int32),
+            jax.ShapeDtypeStruct((B, Hp, Wp), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, 1), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((3, BAND_H, Wp), jnp.int32)],
         interpret=interpret,
     )(f0, f1, f2)
-    return mask, counts
+    return mask, counts[:, 0, 0]
 
 
-def pad_frames(x: jax.Array) -> jax.Array:
-    """Zero-pad (B, H, W, 3) frames to the cascade's (BAND_H, LANE_W) tile.
+def planar_frames(x: jax.Array) -> jax.Array:
+    """(B, H, W, 3) frames -> zero-padded planar (B, 3, H', W') int32.
 
-    Zero is the correct frame fill: framediff of identical zeros is 0,
-    which is exactly dilate's out-of-image fill — the kernel handles the
-    erode fill itself via the true (H, W) mask.
+    The colour planes lead so the lane axis is the padded width, never the
+    3 channels (a channels-last block would pad 3 lanes out to 128).  Zero
+    is the correct frame fill: framediff of identical zeros is 0, which is
+    exactly dilate's out-of-image fill — the kernel handles the erode fill
+    itself via the true (H, W) mask.
     """
     B, H, W, _ = x.shape
     hp, wp = frame_pad(H, W)
+    x = jnp.moveaxis(x.astype(jnp.int32), -1, 1)
     if hp == H and wp == W:
         return x
-    return jnp.pad(x, ((0, 0), (0, hp - H), (0, wp - W), (0, 0)))
+    return jnp.pad(x, ((0, 0), (0, 0), (0, hp - H), (0, wp - W)))

@@ -1,63 +1,47 @@
-"""Single interpret-mode switch for every Pallas launch in the repo.
+"""Runtime policy shared by every Pallas launch: kernel mode and compile cache.
 
-Every kernel module used to hardcode ``interpret: bool = True`` in its
-launcher signature, which meant a TPU run had to touch each call site to
-compile anything.  Instead, launchers now default to ``interpret=None``
-and resolve the effective mode here: the ``REPRO_PALLAS_INTERPRET`` env
-knob (default ON — this container is CPU-only and CI runs the kernels in
-interpret mode) flips every launch in the repo to compiled in one place:
+The backend decides the kernel mode, with no switch: launchers default to
+``interpret=None`` and resolve it here, compiled on a TPU backend and
+interpreted on the CPU one (tests pin the CPU with ``JAX_PLATFORMS=cpu``).
+Any other backend is an error, never a silent fallback.  An explicit
+per-call ``interpret=`` still wins, which is how a test compiles a kernel
+for a described TPU from a CPU host.
 
-    REPRO_PALLAS_INTERPRET=0 python -m pytest ...      # TPU: compile all
-
-Passing an explicit ``interpret=`` to any launcher still wins — tests that
-pin a mode stay pinned.  The env var is read per resolution call, so it
-must be set before the first trace of a given shape (jit caches bake the
-mode into the compiled artifact; flipping mid-process only affects
-not-yet-traced shapes).
+``enable_compile_cache`` is the one place that points JAX's persistent
+compilation cache somewhere: ``$JAX_COMPILATION_CACHE_DIR`` when it is
+set, else the fixed ``<repo>/.cache/jax``.
 """
 from __future__ import annotations
 
-import functools
 import os
 from typing import Optional
 
-ENV_KNOB = "REPRO_PALLAS_INTERPRET"
-
-
-def interpret_default() -> bool:
-    """The repo-wide interpret mode: ON unless ``REPRO_PALLAS_INTERPRET=0``."""
-    return os.environ.get(ENV_KNOB, "1") != "0"
+#: the persistent compile cache's home when ``JAX_COMPILATION_CACHE_DIR``
+#: is unset — a fixed path, because the path is part of the cache key
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".cache", "jax")
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
-    """An explicit per-call ``interpret=`` wins; ``None`` means the knob."""
-    return interpret_default() if interpret is None else bool(interpret)
-
-
-@functools.lru_cache(maxsize=1)
-def compiled_available() -> bool:
-    """Whether this backend can lower a Pallas kernel with interpret=False.
-
-    Probed once per process with a tiny single-block copy kernel.  On the
-    CPU backend of current jax this raises ``Only interpret mode is
-    supported on CPU backend`` — the compiled-mode tests and BENCH rows
-    use this probe to skip (tests) or record their actual substrate
-    (benchmarks) instead of misrepresenting interpreted numbers as
-    compiled ones.  On a TPU runtime it returns True and
-    ``REPRO_PALLAS_INTERPRET=0`` exercises the real compiled path.
-    """
+    """An explicit per-call ``interpret=`` wins; ``None`` follows the
+    backend: compiled on TPU, interpreted on CPU, an error elsewhere."""
+    if interpret is not None:
+        return bool(interpret)
     import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    def _copy(x_ref, o_ref):
-        o_ref[...] = x_ref[...]
-
-    try:
-        x = jnp.zeros((8, 128), jnp.float32)
-        pl.pallas_call(
-            _copy, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-            interpret=False)(x)
-        return True
-    except Exception:
+    backend = jax.default_backend()
+    if backend == "tpu":
         return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile on a TPU backend and are interpreted on "
+        f"the CPU one; the default backend {backend!r} is neither")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory."""
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or REPO_CACHE_DIR
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -49,46 +49,51 @@ def _associate_kernel(emb_ref, trk_ref, cq_ref, tq_ref, thr_ref,
     """One fused score + greedy-assign pass.
 
     emb (M, D) crop embeddings, trk (K, D) track embeddings (both
-    L2-normalized by the wrapper), cq (M,) / tq (K,) int32 query ids,
-    thr (M,) per-crop acceptance floors -> assign (M,) int32 (track row
-    index or -1) and sim (M,) f32 (the best *available* score each crop
-    saw, ``NEG_INF`` when nothing of its query was unclaimed).
+    L2-normalized by the wrapper), cq (M, 1) / tq (1, K) int32 query ids,
+    thr (M, 1) per-crop acceptance floors -> assign (M, 1) int32 (track
+    row index or -1) and sim (M, 1) f32 (the best *available* score each
+    crop saw, ``NEG_INF`` when nothing of its query was unclaimed).
 
-    The greedy loop is fully vectorized (one-hot row selects, no dynamic
-    gathers), so the same body lowers compiled and interpreted.
+    Every operand and carry is 2-D — column vectors for per-crop data,
+    row vectors for per-track data, the claimed set as an int32 row — so
+    Mosaic never has to reshape a 1-D vector.  The greedy loop is fully
+    vectorized (one-hot row selects, first-index argmax by a masked min),
+    so the same body lowers compiled and interpreted.
     """
     emb = emb_ref[...]                         # (M, D)
     trk = trk_ref[...]                         # (K, D)
-    cq = cq_ref[...]                           # (M,)
-    tq = tq_ref[...]                           # (K,)
-    thr = thr_ref[...]                         # (M,)
+    thr = thr_ref[...]                         # (M, 1)
     M = emb.shape[0]
     K = trk.shape[0]
-    s = jnp.dot(emb, trk.T,
-                preferred_element_type=jnp.float32)          # (M, K)
-    s = jnp.where(cq[:, None] == tq[None, :], s, NEG_INF)
-    rows = jnp.arange(M, dtype=jnp.int32)
-    cols = jnp.arange(K, dtype=jnp.int32)
+    # full f32 precision: scores are compared against acceptance floors,
+    # so a bf16 pass on the MXU could flip a match the oracle keeps
+    s = jax.lax.dot_general(emb, trk, (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)  # (M, K)
+    s = jnp.where(cq_ref[...] == tq_ref[...], s, NEG_INF)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (M, 1), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
 
     def body(i, carry):
         claimed, assign, sim = carry
-        onei = rows == i
-        row = jnp.sum(jnp.where(onei[:, None], s, 0.0), axis=0)  # s[i]
+        onei = rows == i                                          # (M, 1)
+        row = jnp.sum(jnp.where(onei, s, 0.0), axis=0,
+                      keepdims=True)                              # s[i]
         thr_i = jnp.sum(jnp.where(onei, thr, 0.0))
-        avail = jnp.where(claimed, NEG_INF, row)
+        avail = jnp.where(claimed > 0, NEG_INF, row)
         best = jnp.max(avail)
-        j = jnp.argmax(avail).astype(jnp.int32)
+        j = jnp.min(jnp.where(avail == best, cols, K))   # first argmax
         ok = best >= thr_i
-        claimed = claimed | ((cols == j) & ok)
+        claimed = jnp.where((cols == j) & ok, 1, claimed)
         assign = jnp.where(onei, jnp.where(ok, j, -1), assign)
         sim = jnp.where(onei, best, sim)
         return claimed, assign, sim
 
     _, assign, sim = jax.lax.fori_loop(
         0, M, body,
-        (jnp.zeros((K,), jnp.bool_),
-         jnp.full((M,), -1, jnp.int32),
-         jnp.full((M,), NEG_INF, jnp.float32)))
+        (jnp.zeros((1, K), jnp.int32),
+         jnp.full((M, 1), -1, jnp.int32),
+         jnp.full((M, 1), NEG_INF, jnp.float32)))
     assign_ref[...] = assign
     sim_ref[...] = sim
 
@@ -101,16 +106,14 @@ def associate_pallas(emb: jax.Array, trk: jax.Array, crop_q: jax.Array,
     interpret = resolve_interpret(interpret)
     M, D = emb.shape
     K = trk.shape[0]
-    return pl.pallas_call(
+    whole = lambda r, c: pl.BlockSpec((r, c), lambda: (0, 0))  # noqa: E731
+    assign, sim = pl.pallas_call(
         _associate_kernel,
-        in_specs=[pl.BlockSpec((M, D), lambda: (0, 0)),
-                  pl.BlockSpec((K, D), lambda: (0, 0)),
-                  pl.BlockSpec((M,), lambda: (0,)),
-                  pl.BlockSpec((K,), lambda: (0,)),
-                  pl.BlockSpec((M,), lambda: (0,))],
-        out_specs=(pl.BlockSpec((M,), lambda: (0,)),
-                   pl.BlockSpec((M,), lambda: (0,))),
-        out_shape=(jax.ShapeDtypeStruct((M,), jnp.int32),
-                   jax.ShapeDtypeStruct((M,), jnp.float32)),
+        in_specs=[whole(M, D), whole(K, D), whole(M, 1), whole(1, K),
+                  whole(M, 1)],
+        out_specs=(whole(M, 1), whole(M, 1)),
+        out_shape=(jax.ShapeDtypeStruct((M, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((M, 1), jnp.float32)),
         interpret=interpret,
-    )(emb, trk, crop_q, trk_q, thr)
+    )(emb, trk, crop_q.reshape(M, 1), trk_q.reshape(1, K), thr.reshape(M, 1))
+    return assign[:, 0], sim[:, 0]

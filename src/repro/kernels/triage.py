@@ -1,19 +1,19 @@
-"""Pallas TPU kernels: cascade triage + escalation compaction (core C1).
+"""Pallas TPU kernel: cascade triage + escalation compaction (core C1).
 
-One pass over a batch of edge confidences produces route codes, escalation
-buffer slots (stable prefix-sum compaction) and the escalated count.  This
-is the per-batch hot path of the SurveilEdge allocator: on TPU it runs as a
-single VMEM-resident block (batch sizes are << VMEM), avoiding three
-separate elementwise+scan launches.
+One pass over the whole fleet's (E, N) tick matrix produces route codes,
+escalation buffer slots (stable per-row prefix-sum compaction) and each
+row's escalated count, with an (E, 2) per-row runtime threshold matrix:
+every edge's triage and compaction in ONE launch per scheduler tick.  The
+one-edge entry points (``triage_dynamic_pallas``, ``triage_pallas``) are
+single-row launches of the same kernel.
 
-Two granularities share one kernel body:
-
-  * ``triage_dynamic_pallas`` — one edge's (N,) batch, thresholds as a (2,)
-    runtime input (``triage_pallas`` delegates here with its static
-    alpha/beta packed into that input).
-  * ``triage_fleet_pallas`` — the whole fleet's (E, N) tick matrix with an
-    (E, 2) per-edge threshold matrix: every edge's triage + compaction in
-    ONE launch per scheduler tick, instead of one launch per edge per tick.
+Rows are independent, so the grid tiles them (``parallel``); each row's
+lanes are walked in ``LANE_BLOCK``-wide blocks (``arbitrary``: in order),
+and the row's escalation count block stays resident across them as the
+compaction carry.  Inside a block the prefix sum is a matmul with an
+upper-triangular ones matrix on the MXU: 0/1 operands are exact in bf16
+and the f32 accumulation is exact up to 2**24, so the slots equal a
+cumsum's bit for bit (Mosaic has no cumsum lowering).
 """
 from __future__ import annotations
 
@@ -23,107 +23,96 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.runtime import resolve_interpret
 
+#: lanes per block along N — also the side of the prefix-sum triangle
+LANE_BLOCK = 512
+#: VMEM elements of one (rows, lanes) block — lanes count at least 128,
+#: the vreg width a narrow block pads to: 512 KiB per f32/i32 operand, so
+#: the double-buffered conf/routes/slots blocks stay a few MiB of VMEM
+BLOCK_ELEMS = 1 << 17
 
-def _triage_dyn_kernel(conf_ref, ab_ref, routes_ref, slots_ref, count_ref, *,
-                       capacity: int):
-    """Fused triage + stable compaction, alpha/beta as a (2,) runtime input.
 
-    Baking alpha/beta into the trace would force a retrace every time
-    Eqs. 8-9 move the thresholds — i.e. every scheduler tick.  Reading them
-    from VMEM keeps the per-tick hot path at a single cached compilation.
-    """
-    conf = conf_ref[...]
-    alpha = ab_ref[0]
-    beta = ab_ref[1]
+def _triage_fleet_kernel(conf_ref, ab_ref, routes_ref, slots_ref, count_ref,
+                         *, capacity: int):
+    """One (rows, lanes) block of the fleet tick matrix.
+
+    ``count_ref`` is the rows' (TR, 1) escalation count, resident across
+    the lane blocks of those rows: it carries the escalations of earlier
+    blocks into this block's slot numbers and ends as the row total."""
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        count_ref[...] = jnp.zeros_like(count_ref)
+
+    conf = conf_ref[...]                       # (TR, TN)
+    alpha = ab_ref[:, 0:1]                     # (TR, 1) broadcast over lanes
+    beta = ab_ref[:, 1:2]
     routes = jnp.where(conf > alpha, 0,
                        jnp.where(conf < beta, 1, 2)).astype(jnp.int32)
     esc = routes == 2
-    pos = jnp.cumsum(esc.astype(jnp.int32)) - 1
-    slots = jnp.where(esc & (pos < capacity), pos, -1).astype(jnp.int32)
+    tn = conf.shape[1]
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (tn, tn), 0)
+           <= jax.lax.broadcasted_iota(jnp.int32, (tn, tn), 1))
+    inclusive = jnp.dot(jnp.where(esc, 1.0, 0.0).astype(jnp.bfloat16),
+                        jnp.where(tri, 1.0, 0.0).astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32)
+    pos = count_ref[...] + inclusive.astype(jnp.int32) - 1
     routes_ref[...] = routes
-    slots_ref[...] = slots
-    count_ref[0] = jnp.sum(esc.astype(jnp.int32))
+    slots_ref[...] = jnp.where(esc & (pos < capacity), pos, -1)
+    count_ref[...] += jnp.sum(esc.astype(jnp.int32), axis=1, keepdims=True)
+
+
+def triage_fleet_pallas(conf: jax.Array, thresholds: jax.Array, *,
+                        capacity: int, interpret: Optional[bool] = None):
+    """conf (E, N) f32, thresholds (E, 2) f32 [alpha, beta] per row ->
+    (routes (E, N) i32, slots (E, N) i32, counts (E,) i32).
+
+    The ``ops`` wrappers pad both axes to power-of-two buckets, which
+    always tile; any other extent falls back to one block along it."""
+    interpret = resolve_interpret(interpret)
+    E, N = conf.shape
+    tn = LANE_BLOCK if N % LANE_BLOCK == 0 else N
+    tr = min(E, max(8, BLOCK_ELEMS // max(tn, 128)))
+    tr = tr if E % tr == 0 else E
+    kernel = functools.partial(_triage_fleet_kernel, capacity=capacity)
+    block = pl.BlockSpec((tr, tn), lambda r, j: (r, j))
+    per_row = lambda w: pl.BlockSpec((tr, w), lambda r, j: (r, 0))  # noqa: E731
+    routes, slots, counts = pl.pallas_call(
+        kernel,
+        grid=(E // tr, N // tn),
+        in_specs=[block, per_row(2)],
+        out_specs=(block, block, per_row(1)),
+        out_shape=(jax.ShapeDtypeStruct((E, N), jnp.int32),
+                   jax.ShapeDtypeStruct((E, N), jnp.int32),
+                   jax.ShapeDtypeStruct((E, 1), jnp.int32)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(conf, thresholds)
+    return routes, slots, counts[:, 0]
 
 
 def triage_dynamic_pallas(conf: jax.Array, thresholds: jax.Array, *,
                           capacity: int, interpret: Optional[bool] = None):
     """conf (N,) f32, thresholds (2,) f32 [alpha, beta] ->
-    (routes (N,) i32, slots (N,) i32, count (1,) i32)."""
-    interpret = resolve_interpret(interpret)
-    (N,) = conf.shape
-    kernel = functools.partial(_triage_dyn_kernel, capacity=capacity)
-    return pl.pallas_call(
-        kernel,
-        in_specs=[pl.BlockSpec((N,), lambda: (0,)),
-                  pl.BlockSpec((2,), lambda: (0,))],
-        out_specs=(pl.BlockSpec((N,), lambda: (0,)),
-                   pl.BlockSpec((N,), lambda: (0,)),
-                   pl.BlockSpec((1,), lambda: (0,))),
-        out_shape=(jax.ShapeDtypeStruct((N,), jnp.int32),
-                   jax.ShapeDtypeStruct((N,), jnp.int32),
-                   jax.ShapeDtypeStruct((1,), jnp.int32)),
-        interpret=interpret,
-    )(conf, thresholds)
+    (routes (N,) i32, slots (N,) i32, count (1,) i32).
+
+    Thresholds are runtime data, so Eqs. 8-9 moving them every tick never
+    retraces; this is the fleet kernel on a single row."""
+    routes, slots, count = triage_fleet_pallas(
+        conf[None], thresholds[None], capacity=capacity, interpret=interpret)
+    return routes[0], slots[0], count
 
 
 def triage_pallas(conf: jax.Array, *, alpha: float, beta: float,
                   capacity: int, interpret: Optional[bool] = None):
     """conf (N,) f32 -> (routes (N,) i32, slots (N,) i32, count (1,) i32).
 
-    Static-threshold convenience wrapper: packs alpha/beta into the dynamic
-    kernel's (2,) threshold input (one kernel body to maintain; the static
-    values still specialize the trace via the input array's contents only,
-    so distinct thresholds share one compilation).
-    """
+    Static-threshold convenience wrapper: packs alpha/beta into the
+    dynamic kernel's (2,) threshold input, so distinct thresholds share
+    one compilation."""
     thresholds = jnp.asarray([alpha, beta], jnp.float32)
     return triage_dynamic_pallas(conf, thresholds, capacity=capacity,
                                  interpret=interpret)
-
-
-def _triage_fleet_kernel(conf_ref, ab_ref, routes_ref, slots_ref, count_ref,
-                         *, capacity: int):
-    """(E, N) fleet tick matrix, per-edge (E, 2) runtime thresholds.
-
-    Row e is edge e's padded per-tick batch; compaction (cumsum along the
-    camera axis) and the escalation-capacity clamp are per row, so each
-    edge keeps its own private escalation buffer exactly as in the
-    one-edge kernel.  The whole fleet is one VMEM-resident block: for the
-    city-scale operating point (64 edges x 512-wide tick buckets) the
-    inputs are ~130 KB, far below VMEM, and the launch count per tick
-    drops from E to 1.
-    """
-    conf = conf_ref[...]                       # (E, N)
-    alpha = ab_ref[:, 0:1]                     # (E, 1) broadcast over cameras
-    beta = ab_ref[:, 1:2]
-    routes = jnp.where(conf > alpha, 0,
-                       jnp.where(conf < beta, 1, 2)).astype(jnp.int32)
-    esc = routes == 2
-    pos = jnp.cumsum(esc.astype(jnp.int32), axis=1) - 1
-    slots = jnp.where(esc & (pos < capacity), pos, -1).astype(jnp.int32)
-    routes_ref[...] = routes
-    slots_ref[...] = slots
-    count_ref[...] = jnp.sum(esc.astype(jnp.int32), axis=1)
-
-
-def triage_fleet_pallas(conf: jax.Array, thresholds: jax.Array, *,
-                        capacity: int, interpret: Optional[bool] = None):
-    """conf (E, N) f32, thresholds (E, 2) f32 [alpha, beta] per edge ->
-    (routes (E, N) i32, slots (E, N) i32, counts (E,) i32)."""
-    interpret = resolve_interpret(interpret)
-    E, N = conf.shape
-    kernel = functools.partial(_triage_fleet_kernel, capacity=capacity)
-    return pl.pallas_call(
-        kernel,
-        in_specs=[pl.BlockSpec((E, N), lambda: (0, 0)),
-                  pl.BlockSpec((E, 2), lambda: (0, 0))],
-        out_specs=(pl.BlockSpec((E, N), lambda: (0, 0)),
-                   pl.BlockSpec((E, N), lambda: (0, 0)),
-                   pl.BlockSpec((E,), lambda: (0,))),
-        out_shape=(jax.ShapeDtypeStruct((E, N), jnp.int32),
-                   jax.ShapeDtypeStruct((E, N), jnp.int32),
-                   jax.ShapeDtypeStruct((E,), jnp.int32)),
-        interpret=interpret,
-    )(conf, thresholds)

@@ -12,7 +12,7 @@ Target hardware: TPU v5e, 256 chips/pod, 2 pods.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 # v5e hardware constants (per chip) — used by the roofline analysis.
 PEAK_FLOPS_BF16 = 197e12        # FLOP/s
@@ -20,16 +20,23 @@ HBM_BW = 819e9                  # bytes/s
 ICI_BW = 50e9                   # bytes/s per link (~unidirectional)
 
 
+def _auto(n: int):
+    """Auto axes: the sharding rules here place arrays with
+    ``with_sharding_constraint`` and ``shard_map``, which need Auto axes
+    (``jax.make_mesh`` defaults to Explicit ones)."""
+    return (AxisType.Auto,) * n
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh() -> Mesh:
     """Single-device mesh for CPU smoke tests (data=1, model=1)."""
     return jax.make_mesh((1, 1), ("data", "model"),
-                         devices=jax.devices()[:1])
+                         devices=jax.devices()[:1], axis_types=_auto(2))
 
 
 def make_fleet_mesh(num_devices: int | None = None) -> Mesh:
@@ -43,7 +50,8 @@ def make_fleet_mesh(num_devices: int | None = None) -> Mesh:
     (set by the sharded CI leg); defaults to every visible device."""
     devices = jax.devices()
     n = len(devices) if num_devices is None else num_devices
-    return jax.make_mesh((n,), ("fleet",), devices=devices[:n])
+    return jax.make_mesh((n,), ("fleet",), devices=devices[:n],
+                         axis_types=_auto(1))
 
 
 def chips(mesh: Mesh) -> int:

@@ -124,20 +124,18 @@ def _superstep_fn(capacity: int, n_shards: int):
         return routes.reshape(S, R, N), slots.reshape(S, R, N), ths
 
     if n_shards > 1:
-        from jax.experimental.shard_map import shard_map
-
         from repro.distributed.sharding import fleet_specs
         from repro.launch.mesh import make_fleet_mesh
 
         sp = fleet_specs()
-        body = shard_map(
+        body = jax.shard_map(
             body, mesh=make_fleet_mesh(n_shards),
             in_specs=(sp["conf"], sp["thresholds"], sp["mask"],
                       sp["drain"], sp["gains"]),
             out_specs=(sp["routes"], sp["slots"], sp["ths_out"]),
-            # the pallas launch has no replication rule; rows are
+            # the pallas launch has no varying-axes rule; rows are
             # independent so shard-local execution IS the semantics
-            check_rep=False)
+            check_vma=False)
     return jax.jit(body)
 
 

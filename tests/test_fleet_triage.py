@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels import triage as TR
 from repro.serving.simulator import Item
 from repro.system import (
     Scenario,
@@ -55,6 +56,33 @@ def test_triage_fleet_matches_ref_fleet():
     want = ref.triage_fleet_ref(conf, th, 8)
     for g, w in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("E,N,capacity", [
+    (8, 8, 3),            # narrow tick, one block
+    (16, 1024, 5),        # two lane blocks: the count carries across them
+    (2048, 128, 40),      # two row blocks of 1024
+    (4, 1536, 600),       # three lane blocks, clamp far into the row
+])
+def test_cumsum_free_compaction_matches_ref(E, N, capacity):
+    """The gridded kernel's matmul prefix sum is bit-exact against the
+    cumsum oracle: slots across lane blocks, the ``capacity`` clamp, and
+    rows with no escalations (all-accept, all-reject, all-pad)."""
+    rng = np.random.default_rng(E * 7 + N)
+    conf = rng.uniform(0, 1, (E, N)).astype(np.float32)
+    th = np.stack([rng.uniform(0.5, 1.0, E), rng.uniform(0.0, 0.45, E)],
+                  axis=1).astype(np.float32)
+    conf[0] = 0.99                          # every lane accepts
+    conf[1] = 0.01                          # every lane rejects
+    conf[2, :] = -1.0                       # a pad row
+    th[3] = (1.0, 0.0)                      # every lane escalates
+    got = TR.triage_fleet_pallas(conf, th, capacity=capacity, interpret=True)
+    want = ref.triage_fleet_ref(conf, th, capacity)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    counts, slots = np.asarray(got[2]), np.asarray(got[1])
+    assert counts[0] == counts[1] == counts[2] == 0
+    assert counts[3] == N and slots[3].max() == min(N, capacity) - 1
 
 
 @pytest.mark.slow

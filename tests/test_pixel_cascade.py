@@ -1,14 +1,12 @@
-"""Fused pixel-cascade kernel: bit-exactness, launch budget, compiled mode.
+"""Fused pixel-cascade kernel: bit-exactness, launch budget, planar layout.
 
 The fused kernel's contract is strict equality: fused == staged (three
 separate Pallas launches) == the independent NumPy oracle, over every
 frame size / threshold / bucket-padding placement.  On top of that, the
 launch-budget acceptance — a pixel_city tick's whole framediff ->
 morphology -> score chain in <= 2 Pallas launches — is asserted with a
-monkeypatched launch counter, and a compiled-mode (interpret=False)
-parity test runs wherever the backend can lower Pallas (skips cleanly on
-CPU, runs for real under ``REPRO_PALLAS_INTERPRET=0`` on TPU — the
-``tier1-compiled`` CI job).
+monkeypatched launch counter.  The kernels are interpreted here (CPU);
+``tests/test_tpu_compile.py`` compiles the same launcher for a v5e.
 """
 import dataclasses
 
@@ -24,17 +22,7 @@ from repro.kernels import ops, ref
 from repro.kernels import pixel_cascade as PC
 from repro.kernels.buckets import (MAX_FRAME_ELEMS, MIN_FRAME_SIDE,
                                    validate_frame_hw)
-from repro.kernels.runtime import compiled_available, interpret_default
 from repro.system.scenario import Scenario, pixel_city
-
-# Pallas-launching tests need either interpret mode (the repo default) or
-# a backend that can lower compiled Pallas; under REPRO_PALLAS_INTERPRET=0
-# on plain CPU (the tier1-compiled job on a CPU runner) they skip cleanly.
-needs_lowering = pytest.mark.skipif(
-    not interpret_default() and not compiled_available(),
-    reason="REPRO_PALLAS_INTERPRET=0 but this backend cannot lower "
-           "compiled Pallas (CPU) — compiled tier runs on TPU runtimes")
-
 
 def _frames(rng, B, H, W):
     return rng.integers(0, 256, (3, B, H, W, 3)).astype(np.int32)
@@ -55,7 +43,6 @@ def _assert_cascade_exact(fs, threshold=40):
 # --- bit-exactness: fused == staged == independent NumPy oracle --------------
 
 
-@needs_lowering
 def test_fused_matches_staged_and_oracle_fixed_shapes():
     """Default camera frame, band-exact, sub-band, and non-lane widths."""
     rng = np.random.default_rng(0)
@@ -64,7 +51,6 @@ def test_fused_matches_staged_and_oracle_fixed_shapes():
         _assert_cascade_exact(_frames(rng, B, H, W))
 
 
-@needs_lowering
 def test_fused_seeded_shape_sweep():
     """Seeded sweep over bucket-padding placements: H straddling band
     multiples, W straddling lane multiples, thresholds across the range.
@@ -82,8 +68,6 @@ def test_fused_property_hypothesis():
     """Hypothesis property over random frame sizes, thresholds, and
     padding placements (skips where hypothesis isn't installed — the
     seeded sweep above keeps the coverage)."""
-    if not interpret_default() and not compiled_available():
-        pytest.skip("no Pallas lowering on this backend")
     hypothesis = pytest.importorskip(
         "hypothesis",
         reason="property tests need hypothesis (pip install -r "
@@ -100,7 +84,6 @@ def test_fused_property_hypothesis():
     prop()
 
 
-@needs_lowering
 def test_sparse_motion_counts():
     """Counts equal the true foreground population on a nearly-static
     scene (one moving block), including a camera with zero motion."""
@@ -117,28 +100,34 @@ def test_sparse_motion_counts():
     assert int(cnt_f[1]) == 0
 
 
-# --- compiled mode -----------------------------------------------------------
+# --- planar frame layout -----------------------------------------------------
 
 
-@pytest.mark.skipif(not compiled_available(),
-                    reason="backend cannot lower compiled Pallas (CPU "
-                           "supports interpret only)")
-def test_compiled_fused_matches_oracle():
-    """interpret=False fused launch, bit-exact vs the NumPy oracle."""
+def test_planar_layout_matches_oracle():
+    """The launcher reads planar (B, 3, H', W') frames: channel c of pixel
+    (y, x) sits at [b, c, y, x], the pad is zero, and the launch on that
+    layout is bit-exact against the NumPy oracle, counts included."""
     rng = np.random.default_rng(3)
-    fs = _frames(rng, 2, 96, 128)
-    f0, f1, f2 = (PC.pad_frames(jnp.asarray(fs[i])) for i in range(3))
-    mask, counts = PC._cascade_call(f0, f1, f2, threshold=40, maxval=255,
-                                    true_hw=(96, 128), interpret=False)
+    B, H, W = 2, 70, 150
+    fs = _frames(rng, B, H, W)
+    planes = [PC.planar_frames(jnp.asarray(fs[i])) for i in range(3)]
+    p0 = np.asarray(planes[0])
+    assert p0.shape == (B, 3, 96, 256) and p0.dtype == np.int32
+    np.testing.assert_array_equal(p0[:, :, :H, :W],
+                                  fs[0].transpose(0, 3, 1, 2))
+    assert not p0[:, :, H:].any() and not p0[:, :, :, W:].any()
+    mask, counts = PC.pixel_cascade_pallas(
+        *planes, threshold=40, maxval=255, true_hw=(H, W), interpret=True)
     mask_np, cnt_np = ref.pixel_cascade_np(fs[0], fs[1], fs[2], 40)
-    np.testing.assert_array_equal(np.asarray(mask)[:, :96, :128], mask_np)
-    np.testing.assert_array_equal(np.asarray(counts).sum(axis=1), cnt_np)
+    mask = np.asarray(mask)
+    np.testing.assert_array_equal(mask[:, :H, :W], mask_np)
+    assert not mask[:, H:].any() and not mask[:, :, W:].any()
+    np.testing.assert_array_equal(np.asarray(counts), cnt_np)
 
 
 # --- launch budget -----------------------------------------------------------
 
 
-@needs_lowering
 def test_pixel_tick_launch_budget(monkeypatch):
     """A pixel tick's framediff->morphology chain is ONE fused Pallas
     launch (<= 2 is the acceptance bar; score_crops is a jit'd model
@@ -172,7 +161,6 @@ def test_pixel_tick_launch_budget(monkeypatch):
     assert launches["n"] == 3          # staged reference: 3 launches
 
 
-@needs_lowering
 def test_pixel_city_tick_detect_launch_budget(monkeypatch):
     """End-to-end: a pixel_city-style fleet tick through ``detect`` stays
     within the <= 2 Pallas-launch budget on the fused path."""
@@ -198,7 +186,6 @@ def test_pixel_city_tick_detect_launch_budget(monkeypatch):
 # --- detect integration ------------------------------------------------------
 
 
-@needs_lowering
 def test_detect_fused_matches_staged_end_to_end():
     """Boxes and crops identical under fused and staged detection."""
     rng = np.random.default_rng(0)
@@ -213,7 +200,6 @@ def test_detect_fused_matches_staged_end_to_end():
         np.testing.assert_array_equal(df.crop, ds.crop)
 
 
-@needs_lowering
 def test_static_scene_skips_ccl(monkeypatch):
     """A motionless tick returns empties WITHOUT running the CCL
     fixpoint — the fused kernel's counts short-circuit it."""

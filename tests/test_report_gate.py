@@ -135,8 +135,7 @@ from report_gate import bench_gate  # noqa: E402
 
 def _bench_doc():
     return {
-        "pallas_compiled_available": False,
-        "interpret_knob": True,
+        "device": {"platform": "cpu", "kind": "cpu", "count": 1},
         "shapes": {
             "B4_96x128": {
                 "rows": {
@@ -144,9 +143,9 @@ def _bench_doc():
                                          "Mpx_s": 24.0,
                                          "substrate": "pallas_interpret",
                                          "pallas_launches": 3},
-                    "fused_compiled": {"us_per_call": 500.0, "Mpx_s": 98.0,
-                                       "substrate": "xla_ref",
-                                       "pallas_launches": 0},
+                    "fused_xla_ref": {"us_per_call": 500.0, "Mpx_s": 98.0,
+                                      "substrate": "xla_ref",
+                                      "pallas_launches": 0},
                 },
             },
         },
@@ -168,7 +167,7 @@ def test_bench_identical_passes(tmp_path):
 def test_bench_throughput_regression_breaches(tmp_path):
     """The acceptance band: >30% slower must breach."""
     fresh = copy.deepcopy(_bench_doc())
-    fresh["shapes"]["B4_96x128"]["rows"]["fused_compiled"]["Mpx_s"] = 60.0
+    fresh["shapes"]["B4_96x128"]["rows"]["fused_xla_ref"]["Mpx_s"] = 60.0
     breaches = bench_gate(*_bench_pair(tmp_path, _bench_doc(), fresh))
     assert len(breaches) == 1 and "throughput" in breaches[0]
 
@@ -176,13 +175,13 @@ def test_bench_throughput_regression_breaches(tmp_path):
 def test_bench_gate_is_one_sided(tmp_path):
     """Getting faster (even 10x) never breaches — regressions only."""
     fresh = copy.deepcopy(_bench_doc())
-    fresh["shapes"]["B4_96x128"]["rows"]["fused_compiled"]["Mpx_s"] = 980.0
+    fresh["shapes"]["B4_96x128"]["rows"]["fused_xla_ref"]["Mpx_s"] = 980.0
     assert bench_gate(*_bench_pair(tmp_path, _bench_doc(), fresh)) == []
 
 
 def test_bench_small_slowdown_within_band_passes(tmp_path):
     fresh = copy.deepcopy(_bench_doc())
-    fresh["shapes"]["B4_96x128"]["rows"]["fused_compiled"]["Mpx_s"] = 70.0
+    fresh["shapes"]["B4_96x128"]["rows"]["fused_xla_ref"]["Mpx_s"] = 70.0
     assert bench_gate(*_bench_pair(tmp_path, _bench_doc(), fresh)) == []
 
 
@@ -190,7 +189,7 @@ def test_bench_substrate_flip_breaches(tmp_path):
     """Interpret baseline vs newly-compiled fresh run must be re-blessed,
     not silently absorbed by the band."""
     fresh = copy.deepcopy(_bench_doc())
-    row = fresh["shapes"]["B4_96x128"]["rows"]["fused_compiled"]
+    row = fresh["shapes"]["B4_96x128"]["rows"]["fused_xla_ref"]
     row["substrate"] = "pallas_compiled"
     row["Mpx_s"] = 500.0
     breaches = bench_gate(*_bench_pair(tmp_path, _bench_doc(), fresh))
@@ -199,7 +198,7 @@ def test_bench_substrate_flip_breaches(tmp_path):
 
 def test_bench_missing_shape_and_row_breach(tmp_path):
     fresh = copy.deepcopy(_bench_doc())
-    del fresh["shapes"]["B4_96x128"]["rows"]["fused_compiled"]
+    del fresh["shapes"]["B4_96x128"]["rows"]["fused_xla_ref"]
     base = copy.deepcopy(_bench_doc())
     base["shapes"]["B8_64x64"] = {"rows": {}}
     breaches = bench_gate(*_bench_pair(tmp_path, base, fresh))
@@ -209,8 +208,8 @@ def test_bench_missing_shape_and_row_breach(tmp_path):
 
 def test_bench_gate_on_committed_baseline():
     """The committed BENCH_pixel_cascade.json gates cleanly against
-    itself and satisfies the acceptance bar: every shape's fused
-    compiled throughput >= 2x its staged interpret baseline."""
+    itself and satisfies the acceptance bar: every shape's fused XLA
+    reference throughput >= 2x its staged interpret baseline."""
     path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
                         "BENCH_pixel_cascade.json")
     assert bench_gate(path, path) == []
@@ -221,7 +220,7 @@ def test_bench_gate_on_committed_baseline():
         rows = shape["rows"]
         assert rows["fused_interpret"]["pallas_launches"] == 1
         assert rows["staged_interpret"]["pallas_launches"] == 3
-        ratio = (rows["fused_compiled"]["Mpx_s"]
+        ratio = (rows["fused_xla_ref"]["Mpx_s"]
                  / rows["staged_interpret"]["Mpx_s"])
         assert ratio >= 2.0, (key, ratio)
         assert "roofline_fraction" in shape["roofline"]["fused"]
@@ -329,7 +328,7 @@ def test_summary_md_records_passing_metrics_too(tmp_path):
 def test_bench_gate_collects_checks(tmp_path):
     checks = []
     fresh = copy.deepcopy(_bench_doc())
-    fresh["shapes"]["B4_96x128"]["rows"]["fused_compiled"]["Mpx_s"] = 60.0
+    fresh["shapes"]["B4_96x128"]["rows"]["fused_xla_ref"]["Mpx_s"] = 60.0
     bench_gate(*_bench_pair(tmp_path, _bench_doc(), fresh), checks=checks)
     bad = [c for c in checks if not c.ok]
     assert len(bad) == 1 and bad[0].metric == "Mpx_s"
@@ -340,11 +339,10 @@ def test_bench_gate_collects_checks(tmp_path):
 
 
 def test_bench_substrate_filter_skips_other_substrates(tmp_path):
-    """A regression in an xla_ref (compiled-tier) row must NOT fail a
-    gate restricted to pallas_interpret rows — compiled rows remain
-    nightly/TPU business on a PR CPU runner."""
+    """A regression in an xla_ref row must NOT fail a gate restricted to
+    pallas_interpret rows — other substrates gate where they run."""
     fresh = copy.deepcopy(_bench_doc())
-    fresh["shapes"]["B4_96x128"]["rows"]["fused_compiled"]["Mpx_s"] = 10.0
+    fresh["shapes"]["B4_96x128"]["rows"]["fused_xla_ref"]["Mpx_s"] = 10.0
     pair = _bench_pair(tmp_path, _bench_doc(), fresh)
     assert bench_gate(*pair, substrates=["pallas_interpret"]) == []
     assert bench_gate(*pair) != []           # unfiltered still catches it

@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels import similarity as SIM
 from repro.serving.simulator import Item
 from repro.system import (
     QuerySpec,
@@ -45,6 +46,28 @@ def test_associate_pallas_matches_ref(m, k, d):
     np.testing.assert_array_equal(np.asarray(ap), np.asarray(ar))
     np.testing.assert_allclose(np.asarray(sp), np.asarray(sr),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_associate_2d_layout_matches_ref():
+    """The kernel's 2-D operand layout — (M, 1) crop ids and floors, a
+    (1, K) track-id row, an int32 claimed row — is bit-exact against the
+    oracle, including tied best scores (first index wins, like argmax),
+    crops whose query has no unclaimed track left, and pad ids."""
+    rng = np.random.default_rng(5)
+    emb, trk, cq, tq, thr = _rand_problem(rng, 24, 16, 32, nq=3)
+    trk[9] = trk[4]                         # a tie: row 4 must win it
+    emb[0] = trk[4]
+    cq[0], tq[4], tq[9] = 2, 2, 2
+    tq[tq == 1] = 0                         # query 1 has no tracks at all
+    cq[-3:], tq[-2:] = -1, -2               # pad crops and pad tracks
+    thr[:4] = -2.0                          # early crops claim greedily
+    a, s = SIM.associate_pallas(emb, trk, cq, tq, thr, interpret=True)
+    ar, sr = ref.associate_tracks_ref(emb, trk, cq, tq, thr)
+    np.testing.assert_array_equal(np.asarray(a), ar)
+    np.testing.assert_allclose(np.asarray(s), sr, rtol=1e-5, atol=1e-5)
+    assert ar[0] == 4
+    assert np.all(ar[cq == 1] == -1) and np.all(ar[-3:] == -1)
+    assert np.all(sr[cq == 1] == np.float32(-1e30))
 
 
 def test_associate_empty_table_and_empty_crops():
