@@ -55,7 +55,9 @@ def label_components(mask: jax.Array, max_iters: int = 256) -> jax.Array:
         new = nb_min(lab)
         return new, jnp.any(new != lab), it + 1
 
-    lab, _, _ = jax.lax.while_loop(cond, body, (init, jnp.bool_(True), 0))
+    with jax.named_scope("ccl"):
+        lab, _, _ = jax.lax.while_loop(cond, body,
+                                       (init, jnp.bool_(True), 0))
     return jnp.where(fg, lab, -1)
 
 

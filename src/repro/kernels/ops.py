@@ -91,9 +91,10 @@ def pixel_cascade(f0: jax.Array, f1: jax.Array, f2: jax.Array, *,
     f0, f1, f2 = (x.astype(jnp.int32) for x in (f0, f1, f2))
     if use_pallas and fused:
         H, W = f0.shape[1], f0.shape[2]
-        mask, counts = _pc.pixel_cascade_pallas(
-            *(_pc.planar_frames(x) for x in (f0, f1, f2)),
-            threshold=threshold, maxval=maxval, true_hw=(H, W))
+        with jax.named_scope("pixel_cascade"):
+            mask, counts = _pc.pixel_cascade_pallas(
+                *(_pc.planar_frames(x) for x in (f0, f1, f2)),
+                threshold=threshold, maxval=maxval, true_hw=(H, W))
         return mask[:, :H, :W], counts
     if not use_pallas:
         mask = _ref.pixel_cascade_ref(f0, f1, f2, threshold, maxval)
@@ -208,7 +209,8 @@ def _triage_fleet(conf: jax.Array, thresholds: jax.Array, *, capacity: int,
                   use_pallas: bool = True):
     if not use_pallas:
         return _ref.triage_fleet_ref(conf, thresholds, capacity)
-    return _tr.triage_fleet_pallas(conf, thresholds, capacity=capacity)
+    with jax.named_scope("triage_fleet"):
+        return _tr.triage_fleet_pallas(conf, thresholds, capacity=capacity)
 
 
 _bucket_q = _bk.bucket_q
@@ -277,8 +279,9 @@ def triage_fleet(conf: jax.Array, thresholds: jax.Array, *, capacity: int,
 @functools.partial(jax.jit, static_argnames=("iters", "min_count"))
 def _calibrate_fleet_pallas(scores: jax.Array, truths: jax.Array, *,
                             iters: int, min_count: int):
-    return _ca.calibrate_fleet_pallas(scores, truths, iters=iters,
-                                      min_count=min_count)
+    with jax.named_scope("calibrate_fleet"):
+        return _ca.calibrate_fleet_pallas(scores, truths, iters=iters,
+                                          min_count=min_count)
 
 
 def calibrate_fleet(scores, truths, *, iters: int = 8, min_count: int = 8,
@@ -339,7 +342,8 @@ def calibrate_fleet(scores, truths, *, iters: int = 8, min_count: int = 8,
 
 @jax.jit
 def _associate_pallas(emb, trk, crop_q, trk_q, thr):
-    return _sim.associate_pallas(emb, trk, crop_q, trk_q, thr)
+    with jax.named_scope("associate_tracks"):
+        return _sim.associate_pallas(emb, trk, crop_q, trk_q, thr)
 
 
 def associate_tracks(emb, trk, crop_q, trk_q, thr, *,

@@ -68,7 +68,6 @@ class SimResult:
     uploaded_bytes: int
     escalated: int
     per_node_busy: Dict[int, float]
-    trace: List[Tuple[float, int, float]]      # (t, node, latency)
 
     # --- metrics --------------------------------------------------------------
     def f_score(self, lam: float = 2.0) -> float:
@@ -155,7 +154,6 @@ class CloudEdgeSim:
         lat: List[float] = []
         dec: List[bool] = []
         tru: List[bool] = []
-        trace: List[Tuple[float, int, float]] = []
         self._uploaded = 0
         self._escalated = 0
         self._cloud_tx: Dict[int, float] = {}
@@ -182,11 +180,10 @@ class CloudEdgeSim:
             if not node_busy[node]:
                 start_service(t, node)
 
-        def finish(t, it, accept: bool, node: int):
+        def finish(t, it, accept: bool):
             lat.append(t - it.t_arrival)
             dec.append(accept)
             tru.append(it.is_query)
-            trace.append((it.t_arrival, node, t - it.t_arrival))
 
         for it in sorted(items, key=lambda x: x.t_arrival):
             push(it.t_arrival, "arrive", it)
@@ -223,9 +220,9 @@ class CloudEdgeSim:
                 self.db.put(f"Q{node}", self.sched.nodes[node].queue_len)
                 if phase == "cloud":
                     # ground-truth classifier (paper: ResNet-152 == truth)
-                    finish(t, it, it.is_query, node)
+                    finish(t, it, it.is_query)
                 elif scheme == "edge_only":
-                    finish(t, it, it.conf > 0.5, node)
+                    finish(t, it, it.conf > 0.5)
                 else:
                     route = self.sched.thresholds.triage(it.conf)
                     if route == "escalate":
@@ -233,7 +230,7 @@ class CloudEdgeSim:
                         self._uploaded += it.nbytes
                         push(self._tx_done(t, it.nbytes), "at_cloud", (it, t))
                     else:
-                        finish(t, it, route == "accept", node)
+                        finish(t, it, route == "accept")
                 if queues[node]:
                     start_service(t, node)
 
@@ -245,5 +242,4 @@ class CloudEdgeSim:
             uploaded_bytes=self._uploaded,
             escalated=self._escalated,
             per_node_busy=busy_time,
-            trace=trace,
         )

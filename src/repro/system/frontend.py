@@ -11,10 +11,12 @@ event loop consumes.  Two implementations exist:
   pixel path: rendered frames -> Pallas framediff/morphology -> moving
   object crops -> CQ-classifier confidences.
 
-Frontends may record per-stage wall-clock seconds in ``self._timings``
-while building the stream; ``run_query`` merges ``Frontend.timings`` into
-``QueryReport.stage_timings`` next to the engine's own triage timing, so a
-report shows where a frames-to-answers run actually spent its time.
+``stream`` takes the call's ``Spans`` (``repro.system.spans``); a
+frontend times its stages in spans under the call's ``stream`` span and
+records their seconds in ``self._timings``.  ``run_query`` merges
+``Frontend.timings`` into ``QueryReport.stage_timings`` next to the
+engine's own keys, so a report shows where a frames-to-answers run
+actually spent its time.
 """
 from __future__ import annotations
 
@@ -24,6 +26,7 @@ from typing import Dict, List, Optional, Sequence
 
 from repro.serving.simulator import Item
 from repro.system.scenario import Scenario, synthetic_confidence_stream
+from repro.system.spans import Spans
 
 
 def rehome(items: Sequence[Item], sc: Scenario) -> List[Item]:
@@ -45,8 +48,10 @@ class Frontend(abc.ABC):
         self._timings: Dict[str, float] = {}
 
     @abc.abstractmethod
-    def stream(self, sc: Scenario) -> List[Item]:
-        """Items sorted by arrival time, homed onto ``sc``'s edges."""
+    def stream(self, sc: Scenario, spans: Optional[Spans] = None
+               ) -> List[Item]:
+        """Items sorted by arrival time, homed onto ``sc``'s edges;
+        stages are timed in ``spans`` (a fresh ``Spans`` when None)."""
 
     @property
     def timings(self) -> Dict[str, float]:
@@ -63,7 +68,8 @@ class ConfidenceStreamFrontend(Frontend):
         super().__init__()
         self._items = items
 
-    def stream(self, sc: Scenario) -> List[Item]:
+    def stream(self, sc: Scenario, spans: Optional[Spans] = None
+               ) -> List[Item]:
         if self._items is None:
             return synthetic_confidence_stream(sc)
         return rehome(self._items, sc)

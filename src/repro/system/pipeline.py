@@ -92,6 +92,7 @@ from repro.system.frontend import ConfidenceStreamFrontend
 from repro.system.nodes import NodeBank
 from repro.system.queries import QuerySet, QuerySpec
 from repro.system.scenario import Scenario
+from repro.system.spans import Spans
 from repro.system.superstep import Ctrl, SuperstepDriver
 from repro.system.tracks import TrackStage
 from repro.system.transport import Transport
@@ -129,6 +130,25 @@ def group_arrivals(items: Sequence[Item], interval_s: float
     return out
 
 
+#: ``QueryReport.stage_timings`` key, the span it reads, and whether it
+#: takes the span's self seconds (True) or its inclusive seconds.  With
+#: the frontend's keys, ``associate_s`` and ``engine_finalize_s``, every
+#: key but the four ``triage_*`` parts tiles the call; those split
+#: ``triage_s``.
+STAGE_SPANS = (
+    ("stream_s", "stream", True),
+    ("engine_setup_s", "engine.setup", True),
+    ("engine_drive_s", "engine.drive", True),
+    ("engine_tick_s", "engine.tick", True),
+    ("triage_s", "triage", False),
+    ("triage_plan_s", "triage.plan", False),
+    ("triage_pack_s", "triage.pack", False),
+    ("triage_launch_s", "triage.launch", False),
+    ("triage_fold_s", "triage.fold", False),
+    ("feedback_s", "feedback", False),
+)
+
+
 class SimDriver:
     """Classic discrete-event driver: drain the heap in time order.
 
@@ -149,11 +169,15 @@ class QueryPipeline:
 
     ``driver`` plugs the event-loop strategy (default ``SimDriver``); any
     driver calls the same ``setup`` / ``handle_event`` / ``finalize``
-    seam, so simulated and real-time runs share every handler."""
+    seam, so simulated and real-time runs share every handler.
+    ``spans`` is the call's ``Spans`` (``run_query`` passes its own; a
+    fresh one otherwise): ``stage_timings`` is read from it."""
 
-    def __init__(self, sc: Scenario, driver: Optional[object] = None):
+    def __init__(self, sc: Scenario, driver: Optional[object] = None,
+                 spans: Optional[Spans] = None):
         self.sc = sc
         self.driver = driver
+        self.spans = spans if spans is not None else Spans()
         self.rng = np.random.default_rng(sc.seed + 1)
         # topology: cloud is node 0, edges 1..E (service-time multipliers)
         self.service_s: Dict[int, float] = {
@@ -357,8 +381,9 @@ class QueryPipeline:
                             and self.queries.live_on(it.query, edge)):
                         tb.setdefault((it.query, edge), []).append(it)
             if tb:
-                for done, upd in self.track.tick(t, tb):
-                    self.events.push(done, upd)
+                with self.spans.span("associate"):
+                    for done, upd in self.track.tick(t, tb):
+                        self.events.push(done, upd)
         if self.sc.scheme == "edge_only":
             for edge, batch in live.items():
                 for it in batch:
@@ -598,7 +623,8 @@ class QueryPipeline:
         self.events = EventQueue()
         self.transport = Transport(sc)
         self.nodes = NodeBank(sc, self.service_s, self.rng)
-        self.triage_stage = TriageStage(sc, self.sched, self.transport)
+        self.triage_stage = TriageStage(sc, self.sched, self.transport,
+                                        self.spans)
         self.feedback = FeedbackStage(sc, self.transport)
         self.queries = QuerySet(sc)
         # cross-camera track queries: the fleet-wide track registry exists
@@ -737,7 +763,8 @@ class QueryPipeline:
             task.tx_s = done - t
             self.events.push(done, Transfer(CLOUD, task))
         elif isinstance(ev, TickArrivals):
-            self._on_tick(t, ev.batches, ev.tick)
+            with self.spans.span("engine.tick", tick=ev.tick):
+                self._on_tick(t, ev.batches, ev.tick)
         elif isinstance(ev, Transfer):
             if ev.node in self.nodes.dead:   # died while in transit
                 self._rerouted += 1
@@ -785,13 +812,15 @@ class QueryPipeline:
             # only fires a launch if this tick boundary had no natural
             # TickArrivals (which would have absorbed the release)
             if self._release:
-                self._on_tick(t, {}, ev.tick)
+                with self.spans.span("engine.tick", tick=ev.tick):
+                    self._on_tick(t, {}, ev.tick)
         elif isinstance(ev, FeedbackTick):
             # one fused fleet recalibration launch; the per-row
             # results land as ModelUpdate events at downlink delivery
-            for done, update in self.feedback.tick(
-                    t, self.nodes.dead, self.queries.retired):
-                self.events.push(done, update)
+            with self.spans.span("feedback"):
+                for done, update in self.feedback.tick(
+                        t, self.nodes.dead, self.queries.retired):
+                    self.events.push(done, update)
         elif isinstance(ev, ModelUpdate):
             if ev.kind == "weights":
                 if ev.edge in self.nodes.dead \
@@ -957,9 +986,7 @@ class QueryPipeline:
             thresholds=self.triage_stage.final_thresholds()
             if sc.scheme in ("surveiledge", "surveiledge_fixed") else {},
             stage_timings={**(self._frontend_timings or {}),
-                           "triage_s": self.triage_stage.elapsed_s,
-                           **({"associate_s": trk.elapsed_s}
-                              if trk is not None else {})},
+                           **self._span_timings()},
             alerts=self.alerts.snapshot(),
             submitted_queries=self._submitted,
             shed_queries=self._shed_queries,
@@ -970,13 +997,30 @@ class QueryPipeline:
                          for e in sc.edge_ids},
         )
 
+    def _span_timings(self) -> Dict[str, float]:
+        """The engine's ``stage_timings`` keys, read from its spans."""
+        sp = self.spans
+        out = {key: sp.self_s(name) if own else sp.total(name)
+               for key, name, own in STAGE_SPANS}
+        if self.track is not None:
+            out["associate_s"] = sp.total("associate")
+        return out
+
     def run(self, items: Sequence[Item],
             frontend_timings: Optional[Dict[str, float]] = None
             ) -> MX.QueryReport:
-        """setup -> drive (the injected driver, or SimDriver) -> finalize."""
-        self.setup(items, frontend_timings)
-        (self.driver or SimDriver()).drive(self)
-        return self.finalize()
+        """setup -> drive (the injected driver, or SimDriver) -> finalize,
+        each in its span; ``engine_finalize_s`` joins the report after it
+        is built."""
+        sp = self.spans
+        with sp.span("engine.setup"):
+            self.setup(items, frontend_timings)
+        with sp.span("engine.drive"):
+            (self.driver or SimDriver()).drive(self)
+        with sp.span("engine.finalize"):
+            rep = self.finalize()
+        rep.stage_timings["engine_finalize_s"] = sp.self_s("engine.finalize")
+        return rep
 
 
 def run_query(scenario: Scenario, *,
@@ -1030,6 +1074,9 @@ def run_query(scenario: Scenario, *,
     if frontend is None:
         frontend = ConfidenceStreamFrontend(
             items if items is not None else scenario.items)
-    stream = frontend.stream(scenario)
-    return QueryPipeline(scenario, driver=driver).run(
-        stream, frontend_timings=frontend.timings)
+    spans = Spans()
+    with spans.span("run_query"):
+        with spans.span("stream"):
+            stream = frontend.stream(scenario, spans=spans)
+        return QueryPipeline(scenario, driver=driver, spans=spans).run(
+            stream, frontend_timings=frontend.timings)

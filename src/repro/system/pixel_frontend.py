@@ -26,8 +26,10 @@ frames -> triage -> allocation -> metrics loop.  Ground truth comes from
 the renderer: each detection is matched to the nearest planted sprite
 (unmatched detections are disturbance and count as non-query).
 
-Per-stage wall-clock (render/framediff/classify) is recorded and surfaces
-in ``QueryReport.stage_timings`` next to the engine's triage timing.
+Each tick's render, detect and classify phases run in spans of the call's
+``Spans`` (``render``, ``detect``, ``classify``, each with ``tick=k``);
+their totals surface in ``QueryReport.stage_timings`` as ``render_s``,
+``framediff_s`` and ``classify_s``.
 
 By default the classifier is a freshly initialized (untrained) CQ edge
 model — the full compute path with no training in the loop, for tests and
@@ -39,7 +41,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -56,6 +57,7 @@ from repro.models import transformer as T
 from repro.serving.simulator import Item
 from repro.system.frontend import Frontend
 from repro.system.scenario import Scenario, frame_schedule, scenario_cameras
+from repro.system.spans import Spans
 
 
 def match_truth(box: Box, truth: SV.FrameTruth,
@@ -78,8 +80,9 @@ def match_truth(box: Box, truth: SV.FrameTruth,
 
 def _conf_apply(cfg, params, tokens: jax.Array) -> jax.Array:
     """(N, T) patch tokens -> (N,) P(query object) under the CQ model."""
-    h, _ = T.forward(cfg, params, tokens, remat=False)
-    return confidence_from_logits(T.classify(cfg, params, h), 1)
+    with jax.named_scope("score_crops"):
+        h, _ = T.forward(cfg, params, tokens, remat=False)
+        return confidence_from_logits(T.classify(cfg, params, h), 1)
 
 
 class PixelFrontend(Frontend):
@@ -124,49 +127,50 @@ class PixelFrontend(Frontend):
                 sc.duration_s, sc.interval_s, sc.burst_boost, sc.burst_rate,
                 sc.frame_hw, sc.track_query_ids, sc.embedding_dim)
 
-    def stream(self, sc: Scenario) -> List[Item]:
+    def stream(self, sc: Scenario, spans: Optional[Spans] = None
+               ) -> List[Item]:
         key = self._stream_key(sc)
         if self._cache is not None and self._cache[0] == key:
             _, items, timings = self._cache
             self._timings = dict(timings)
             return list(items)
-        items, timings = self._build(sc)
+        items, timings = self._build(sc, spans if spans is not None
+                                     else Spans())
         self._timings = dict(timings)
         if self._cache_enabled:
             self._cache = (key, list(items), timings)
         return items
 
-    def _build(self, sc: Scenario) -> Tuple[List[Item], Dict[str, float]]:
+    def _build(self, sc: Scenario, spans: Spans
+               ) -> Tuple[List[Item], Dict[str, float]]:
         cams = scenario_cameras(sc)
         schedule = frame_schedule(sc)                        # (T, C)
         rng = np.random.default_rng(sc.seed + 31)
-        t_render = t_framediff = t_classify = 0.0
         items: List[Item] = []
         for k in range(schedule.shape[0]):
-            t0 = time.perf_counter()
-            triples, truths = [], []
-            for j, cam in enumerate(cams):
-                frames, truth = SV.render_triple(cam, schedule[k, j], rng)
-                triples.append(frames)
-                truths.append(truth)
-            batch = np.stack(triples)                # (C, 3, H, W, 3)
-            t_render += time.perf_counter() - t0
+            with spans.span("render", tick=k):
+                triples, truths = [], []
+                for j, cam in enumerate(cams):
+                    frames, truth = SV.render_triple(cam, schedule[k, j],
+                                                     rng)
+                    triples.append(frames)
+                    truths.append(truth)
+                batch = np.stack(triples)                # (C, 3, H, W, 3)
 
-            t0 = time.perf_counter()
-            dets = DP.detect(batch, threshold=self.threshold, crop=self.crop,
-                             min_area=self.min_area,
-                             use_pallas=self.use_pallas, fused=self.fused)
-            t_framediff += time.perf_counter() - t0
+            with spans.span("detect", tick=k):
+                dets = DP.detect(batch, threshold=self.threshold,
+                                 crop=self.crop, min_area=self.min_area,
+                                 use_pallas=self.use_pallas,
+                                 fused=self.fused)
 
             flat = [(j, d) for j, per in enumerate(dets) for d in per]
             if not flat:
                 continue
-            t0 = time.perf_counter()
-            tokens = SV.crops_to_tokens(
-                np.stack([d.crop for _, d in flat]), self.cfg.vocab_size)
-            conf = np.asarray(ops.score_crops(
-                functools.partial(self._conf_fn, self.params), tokens))
-            t_classify += time.perf_counter() - t0
+            with spans.span("classify", tick=k):
+                tokens = SV.crops_to_tokens(
+                    np.stack([d.crop for _, d in flat]), self.cfg.vocab_size)
+                conf = np.asarray(ops.score_crops(
+                    functools.partial(self._conf_fn, self.params), tokens))
             self.launches += 1
 
             nbytes = self.crop * self.crop * 3
@@ -187,5 +191,6 @@ class PixelFrontend(Frontend):
                     emb=SV.crop_embedding(det.crop, sc.embedding_dim)
                     if embed else None))
         items.sort(key=lambda it: it.t_arrival)
-        return items, {"render_s": t_render, "framediff_s": t_framediff,
-                       "classify_s": t_classify}
+        return items, {"render_s": spans.total("render"),
+                       "framediff_s": spans.total("detect"),
+                       "classify_s": spans.total("classify")}

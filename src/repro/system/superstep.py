@@ -54,7 +54,6 @@ from __future__ import annotations
 import bisect
 import dataclasses
 import functools
-import time
 from typing import Dict, FrozenSet, List, Tuple
 
 import numpy as np
@@ -116,8 +115,11 @@ def _superstep_fn(capacity: int, n_shards: int):
             th = jnp.where(m[:, None], new, th)
             return th, th
 
-        _, ths = jax.lax.scan(step, th0, mask)          # (S, R, 2)
+        with jax.named_scope("superstep"):
+            _, ths = jax.lax.scan(step, th0, mask)      # (S, R, 2)
         S, R, N = conf.shape
+        # no scope here: a scope around a Pallas call names its HLO
+        # custom call, and this one's device events are ``body.1``
         routes, slots, _ = _tr.triage_fleet_pallas(
             conf.reshape(S * R, N), ths.reshape(S * R, 2),
             capacity=capacity)
@@ -174,18 +176,18 @@ class SuperstepDriver:
         drops, matching the per-tick driver's ordering)."""
         plan = self._plans.pop(tick, None)
         if plan is None:
-            self._build(tick, ready, ctrl)
+            with self.pipe.spans.span("triage"):
+                self._build(tick, ready, ctrl)
             plan = self._plans.pop(tick)
         return plan
 
     # --- planning + one fused launch ------------------------------------------
     def _build(self, k0: int, ready0: Dict[Key, List[Item]],
                ctrl: Ctrl) -> None:
-        t0 = time.perf_counter()
         pipe, sc = self.pipe, self.sc
+        spans = pipe.spans
         adaptive = sc.scheme == "surveiledge"
         shed = ctrl.overloaded if adaptive else frozenset()
-        next_boundary = pipe.events.next_boundary()
 
         # Greedy segmentation: the current tick always belongs to its
         # own superstep; future arrival ticks join while (a) the run
@@ -196,89 +198,95 @@ class SuperstepDriver:
         # slab stays under the element cap.  Ticks whose pure
         # classification comes back empty are skipped, not counted: the
         # pipeline never asks for a plan on an empty tick.
-        ticks = [k0]
-        readies = [ready0]
-        keys = set(ready0)
-        max_n = max(len(v) for v in ready0.values())
-        order = pipe._tick_order
-        i = bisect.bisect_right(order, k0)
-        while len(ticks) < self.k and i < len(order):
-            k = order[i]
-            if (k + 1) * sc.interval_s >= next_boundary - 1e-9:
-                break
-            i += 1
-            ready = pipe._ready_of(pipe._tick_batches[k])
-            if not ready:
-                continue
-            cand_keys = keys | set(ready)
-            cand_n = max(max_n, max(len(v) for v in ready.values()))
-            if (bucket(len(ticks) + 1, 1) * bucket(len(cand_keys))
-                    * bucket(cand_n)) > MAX_SUPERSTEP_ELEMS:
-                break
-            ticks.append(k)
-            readies.append(ready)
-            keys, max_n = cand_keys, cand_n
+        with spans.span("triage.plan"):
+            next_boundary = pipe.events.next_boundary()
+            ticks = [k0]
+            readies = [ready0]
+            keys = set(ready0)
+            max_n = max(len(v) for v in ready0.values())
+            order = pipe._tick_order
+            i = bisect.bisect_right(order, k0)
+            while len(ticks) < self.k and i < len(order):
+                k = order[i]
+                if (k + 1) * sc.interval_s >= next_boundary - 1e-9:
+                    break
+                i += 1
+                ready = pipe._ready_of(pipe._tick_batches[k])
+                if not ready:
+                    continue
+                cand_keys = keys | set(ready)
+                cand_n = max(max_n, max(len(v) for v in ready.values()))
+                if (bucket(len(ticks) + 1, 1) * bucket(len(cand_keys))
+                        * bucket(cand_n)) > MAX_SUPERSTEP_ELEMS:
+                    break
+                ticks.append(k)
+                readies.append(ready)
+                keys, max_n = cand_keys, cand_n
 
         # pack the slab over the run's active keys only
-        keys_sorted = sorted(keys)
-        ki = {key: r for r, key in enumerate(keys_sorted)}
-        S, R = len(ticks), len(keys_sorted)
-        Sb, Rb, Nb = bucket(S, 1), bucket(R), bucket(max_n)
-        conf = np.full((Sb, Rb, Nb), -1.0, np.float32)
-        mask = np.zeros((Sb, Rb), bool)
-        th0 = np.tile(np.asarray([1.0, 0.0], np.float32), (Rb, 1))
-        drain = np.zeros(Rb, np.float32)
-        stage = pipe.triage_stage
-        for r, key in enumerate(keys_sorted):
-            st = stage.states[key]
-            th0[r] = (st.alpha, st.beta)
-            if adaptive:
-                drain[r] = max(ctrl.edge_drain[key[1]], ctrl.esc_drain)
-        for s, ready in enumerate(readies):
-            for key, items in ready.items():
-                r = ki[key]
+        with spans.span("triage.pack"):
+            keys_sorted = sorted(keys)
+            ki = {key: r for r, key in enumerate(keys_sorted)}
+            S, R = len(ticks), len(keys_sorted)
+            Sb, Rb, Nb = bucket(S, 1), bucket(R), bucket(max_n)
+            conf = np.full((Sb, Rb, Nb), -1.0, np.float32)
+            mask = np.zeros((Sb, Rb), bool)
+            th0 = np.tile(np.asarray([1.0, 0.0], np.float32), (Rb, 1))
+            drain = np.zeros(Rb, np.float32)
+            stage = pipe.triage_stage
+            for r, key in enumerate(keys_sorted):
+                st = stage.states[key]
+                th0[r] = (st.alpha, st.beta)
                 if adaptive:
-                    mask[s, r] = True
-                if key[1] in shed:
-                    continue        # row stays pad: outputs never read
-                row = conf[s, r]
-                row[:len(items)] = [it.conf for it in items]
-                calibrate_row(row, len(items), stage.calibrations[key])
-        proto = next(iter(stage.states.values()))
-        g1u = proto.gamma1 if proto.gamma1_up is None else proto.gamma1_up
-        gains = np.asarray([proto.gamma1, g1u, proto.gamma2,
-                            sc.interval_s], np.float32)
+                    drain[r] = max(ctrl.edge_drain[key[1]], ctrl.esc_drain)
+            for s, ready in enumerate(readies):
+                for key, items in ready.items():
+                    r = ki[key]
+                    if adaptive:
+                        mask[s, r] = True
+                    if key[1] in shed:
+                        continue        # row stays pad: outputs never read
+                    row = conf[s, r]
+                    row[:len(items)] = [it.conf for it in items]
+                    calibrate_row(row, len(items), stage.calibrations[key])
+            proto = next(iter(stage.states.values()))
+            g1u = proto.gamma1 if proto.gamma1_up is None \
+                else proto.gamma1_up
+            gains = np.asarray([proto.gamma1, g1u, proto.gamma2,
+                                sc.interval_s], np.float32)
 
-        n_shards = self.n_shards if Rb % self.n_shards == 0 else 1
-        fn = _superstep_fn(sc.escalation_capacity, n_shards)
-        routes, slots, ths = (np.asarray(a)
-                              for a in fn(conf, th0, mask, drain, gains))
+        with spans.span("triage.launch"):
+            n_shards = self.n_shards if Rb % self.n_shards == 0 else 1
+            fn = _superstep_fn(sc.escalation_capacity, n_shards)
+            routes, slots, ths = (np.asarray(a) for a in
+                                  fn(conf, th0, mask, drain, gains))
         stage.launches += 1
         self.supersteps += 1
 
-        # fold back into per-tick plans
-        for s, (k, ready) in enumerate(zip(ticks, readies)):
-            outs: TickOuts = {}
-            ths_k: TickThs = {}
-            for key, items in ready.items():
-                r = ki[key]
-                if adaptive:
-                    ths_k[key] = (float(ths[s, r, 0]),
-                                  float(ths[s, r, 1]))
-                if key[1] not in shed:
-                    n = len(items)
-                    outs[key] = (routes[s, r, :n], slots[s, r, :n],
-                                 conf[s, r, :n])
-            self._plans[k] = (outs, ths_k)
+        with spans.span("triage.fold"):
+            # fold back into per-tick plans
+            for s, (k, ready) in enumerate(zip(ticks, readies)):
+                outs: TickOuts = {}
+                ths_k: TickThs = {}
+                for key, items in ready.items():
+                    r = ki[key]
+                    if adaptive:
+                        ths_k[key] = (float(ths[s, r, 0]),
+                                      float(ths[s, r, 1]))
+                    if key[1] not in shed:
+                        n = len(items)
+                        outs[key] = (routes[s, r, :n], slots[s, r, :n],
+                                     conf[s, r, :n])
+                self._plans[k] = (outs, ths_k)
 
-        # write the end-of-run thresholds back so the next superstep (or
-        # the end-of-run report) starts where this one ended.  ONLY the
-        # adaptive scheme: the fixed scheme never refreshes, and writing
-        # f32-cast copies would perturb its frozen f64 (alpha, beta).
-        if adaptive:
-            for r, key in enumerate(keys_sorted):
-                stage.states[key] = dataclasses.replace(
-                    stage.states[key],
-                    alpha=float(ths[S - 1, r, 0]),
-                    beta=float(ths[S - 1, r, 1]))
-        stage.elapsed_s += time.perf_counter() - t0
+            # write the end-of-run thresholds back so the next superstep
+            # (or the end-of-run report) starts where this one ended.
+            # ONLY the adaptive scheme: the fixed scheme never refreshes,
+            # and writing f32-cast copies would perturb its frozen f64
+            # (alpha, beta).
+            if adaptive:
+                for r, key in enumerate(keys_sorted):
+                    stage.states[key] = dataclasses.replace(
+                        stage.states[key],
+                        alpha=float(ths[S - 1, r, 0]),
+                        beta=float(ths[S - 1, r, 1]))

@@ -36,7 +36,6 @@ ID switch.  ``track_continuity = 1 - switches / opportunities``.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, List, Tuple
 
 import numpy as np
@@ -78,7 +77,6 @@ class TrackStage:
         self.handoffs = 0
         self.prewarms = 0
         self.prewarm_hits = 0
-        self.elapsed_s = 0.0
 
     # --- warmth ---------------------------------------------------------------
     def _warm_parts(self, query: int, edge: int, t: float) -> Tuple[bool, bool]:
@@ -113,7 +111,6 @@ class TrackStage:
         key (items keep stream order within a batch), so association —
         and therefore every hand-off decision — is deterministic across
         reruns and drivers."""
-        t0 = time.perf_counter()
         # TTL retirement first: a track the fleet lost track_ttl_s ago must
         # not claim this tick's crops
         ttl = self.sc.track_ttl_s
@@ -127,7 +124,6 @@ class TrackStage:
                 if it.emb is not None:
                     crops.append((it, q, e))
         if not crops:
-            self.elapsed_s += time.perf_counter() - t0
             return []
         self.items += len(crops)
         warm_t, cold_t = self.sc.track_thresholds
@@ -198,7 +194,6 @@ class TrackStage:
                     if prev_tid != key[1]:
                         self.id_switches += 1
                 self._gt_last[gk] = key[1]
-        self.elapsed_s += time.perf_counter() - t0
         return out
 
     def _predict_handoff(self, t: float, query: int, tr: _Track,

@@ -25,7 +25,6 @@ rows) for the survivors.
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
@@ -36,6 +35,7 @@ from repro.kernels import ops
 from repro.serving.simulator import Item
 from repro.system.feedback import IDENTITY, calibrate_row
 from repro.system.scenario import Scenario
+from repro.system.spans import Spans
 from repro.system.transport import Transport
 
 # route codes emitted by the triage kernel
@@ -48,10 +48,12 @@ Key = Tuple[int, int]
 class TriageStage:
     """Per-(query, edge) adaptive thresholds + the fused triage hot path."""
 
-    def __init__(self, sc: Scenario, sched: Scheduler, transport: Transport):
+    def __init__(self, sc: Scenario, sched: Scheduler, transport: Transport,
+                 spans: Optional[Spans] = None):
         self.sc = sc
         self.sched = sched
         self.transport = transport
+        self.spans = spans if spans is not None else Spans()
         # Per-(query, edge) Eqs. 8-9 state (the paper runs the adaptation
         # on every edge device per CQ model; one global (alpha, beta)
         # would let one hot edge — or one blurry query — drag every
@@ -83,7 +85,6 @@ class TriageStage:
                 q: w for q in sc.query_ids
                 if (w := w_of.get(tier_of.get(q, 0), 0.0)) > 0.0}
         self.launches = 0
-        self.elapsed_s = 0.0         # wall clock inside triage_tick
 
     # --- Eqs. 8-9, once per (query, edge) per tick ----------------------------
     def refresh(self, t: float, keys: Iterable[Key]) -> None:
@@ -127,37 +128,40 @@ class TriageStage:
         live calibration, not the stale raw score."""
         if not batches:
             return {}
-        t0 = time.perf_counter()
-        qs = sorted({q for q, _ in batches})
-        es = sorted({e for _, e in batches})
-        qi = {q: i for i, q in enumerate(qs)}
-        ei = {e: i for i, e in enumerate(es)}
-        n = max(len(b) for b in batches.values())
-        conf = np.full((len(qs), len(es), n), -1.0, np.float32)
-        # absent (query, edge) rows stay all-pad; give them inert
-        # thresholds (1, 0) like the kernel's own pad rows
-        thresholds = np.tile(np.asarray([1.0, 0.0], np.float32),
-                             (len(qs), len(es), 1))
-        for (q, e), items in batches.items():
-            row = conf[qi[q], ei[e]]
-            row[:len(items)] = [it.conf for it in items]
-            # live recalibration from the cloud->edge feedback loop; pad
-            # lanes stay -1.0 (always 'reject', never a slot).  Shared
-            # with the superstep slab pack — see feedback.calibrate_row.
-            calibrate_row(row, len(items), self.calibrations[(q, e)])
-            st = self.states[(q, e)]
-            thresholds[qi[q], ei[e]] = (st.alpha, st.beta)
-        routes, slots, _ = ops.triage_fleet(
-            conf, thresholds, capacity=self.sc.escalation_capacity)
-        self.launches += 1
-        routes, slots = np.asarray(routes), np.asarray(slots)
-        out = {
-            key: (routes[qi[key[0]], ei[key[1]], :len(items)],
-                  slots[qi[key[0]], ei[key[1]], :len(items)],
-                  conf[qi[key[0]], ei[key[1]], :len(items)])
-            for key, items in batches.items()}
-        self.elapsed_s += time.perf_counter() - t0
-        return out
+        spans = self.spans
+        with spans.span("triage"):
+            with spans.span("triage.pack"):
+                qs = sorted({q for q, _ in batches})
+                es = sorted({e for _, e in batches})
+                qi = {q: i for i, q in enumerate(qs)}
+                ei = {e: i for i, e in enumerate(es)}
+                n = max(len(b) for b in batches.values())
+                conf = np.full((len(qs), len(es), n), -1.0, np.float32)
+                # absent (query, edge) rows stay all-pad; give them inert
+                # thresholds (1, 0) like the kernel's own pad rows
+                thresholds = np.tile(np.asarray([1.0, 0.0], np.float32),
+                                     (len(qs), len(es), 1))
+                for (q, e), items in batches.items():
+                    row = conf[qi[q], ei[e]]
+                    row[:len(items)] = [it.conf for it in items]
+                    # live recalibration from the cloud->edge feedback
+                    # loop; pad lanes stay -1.0 (always 'reject', never a
+                    # slot).  Shared with the superstep slab pack — see
+                    # feedback.calibrate_row.
+                    calibrate_row(row, len(items), self.calibrations[(q, e)])
+                    st = self.states[(q, e)]
+                    thresholds[qi[q], ei[e]] = (st.alpha, st.beta)
+            with spans.span("triage.launch"):
+                routes, slots, _ = ops.triage_fleet(
+                    conf, thresholds, capacity=self.sc.escalation_capacity)
+                routes, slots = np.asarray(routes), np.asarray(slots)
+            self.launches += 1
+            with spans.span("triage.fold"):
+                return {
+                    key: (routes[qi[key[0]], ei[key[1]], :len(items)],
+                          slots[qi[key[0]], ei[key[1]], :len(items)],
+                          conf[qi[key[0]], ei[key[1]], :len(items)])
+                    for key, items in batches.items()}
 
     def add_query(self, query: int, weight: float = 0.0) -> None:
         """Register a runtime-submitted query (live API): fresh threshold

@@ -108,7 +108,7 @@ def test_calibrate_fleet_padding_property():
         np.testing.assert_array_equal(np.asarray(padded_c)[:E],
                                       np.asarray(base_c))
         np.testing.assert_allclose(np.asarray(padded)[E:],
-                                   [[1.0, 0.0]] * extra_rows)
+                                   np.tile([[1.0, 0.0]], (extra_rows, 1)))
 
     prop()
 
