@@ -17,6 +17,7 @@ Two estimators, exactly as the paper uses them:
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Optional, Sequence, Tuple
 
@@ -34,14 +35,18 @@ def adaptive_mean(t_old: float, t_new: float) -> float:
 
 
 def _score_gamma(x: np.ndarray, g: float) -> float:
-    """LHS of Eq. 16 (=0 at the MLE gamma)."""
+    """LHS of Eq. 16 (=0 at the MLE gamma).
+
+    ``np.add.reduce`` is the summation ``np.sum`` runs, without its
+    dispatch; the scalar tail is in Python floats, the same IEEE doubles.
+    """
     d = x - g
     ln = np.log(d)
     n = len(x)
-    s1 = np.sum(1.0 / d)
-    s2 = np.sum(ln)
-    s3 = np.sum(ln * ln)
-    s4 = np.sum(ln / d)
+    s1 = float(np.add.reduce(1.0 / d))
+    s2 = float(np.add.reduce(ln))
+    s3 = float(np.add.reduce(ln * ln))
+    s4 = float(np.add.reduce(ln / d))
     return s1 * (s2 - s3 + s2 * s2 / n) - n * s4
 
 
@@ -52,17 +57,33 @@ def fit_lognormal3(x: Sequence[float],
     Solves Eq. 16 for gamma by bisection on (eps, min(x)), then Eqs. 14-15.
     Falls back to gamma=0 (plain lognormal) if no sign change is bracketed.
     """
-    xa = np.asarray(list(x), dtype=np.float64)
+    return _fit_lognormal3(x, iters)[:3]
+
+
+def _fit_lognormal3(x: Sequence[float], iters: int = 80
+                    ) -> Tuple[float, float, float, int]:
+    """``fit_lognormal3`` plus the number of bisection steps it took.
+
+    The bisection runs at most ``iters`` steps and stops once the midpoint
+    rounds onto an end of the bracket: no later step could move
+    ``0.5 * (lo + hi)`` off that midpoint, so gamma is the same as after
+    all ``iters``.
+    """
+    xa = np.asarray(x, dtype=np.float64)
     if len(xa) < 3 or np.any(xa <= 0):
         raise ValueError("need >=3 positive samples")
     xmin = float(np.min(xa))
     lo, hi = 1e-12, xmin * (1.0 - 1e-9)
     flo, fhi = _score_gamma(xa, lo), _score_gamma(xa, hi)
+    steps = 0
     if flo * fhi > 0:
         gamma = 0.0
     else:
         for _ in range(iters):
             mid = 0.5 * (lo + hi)
+            if mid == lo or mid == hi:
+                break
+            steps += 1
             fm = _score_gamma(xa, mid)
             if flo * fm <= 0:
                 hi, fhi = mid, fm
@@ -72,7 +93,7 @@ def fit_lognormal3(x: Sequence[float],
     ln = np.log(xa - gamma)
     mu = float(np.mean(ln))                       # Eq. 14
     sigma2 = float(np.mean((ln - mu) ** 2))       # Eq. 15
-    return gamma, mu, sigma2
+    return gamma, mu, sigma2, steps
 
 
 @dataclasses.dataclass
@@ -80,35 +101,50 @@ class LatencyEstimator:
     """Combined estimator: Eq. 17 online + lognormal refits every ``refit_every``.
 
     ``predict()`` = blend of the real-time adaptive mean and the lognormal
-    (mean(E[X], Median[X])) long-period prediction, as in the paper.
+    (mean(E[X], Median[X])) long-period prediction, as in the paper.  The
+    long-period value changes only on a refit, so it is computed there;
+    ``t`` is read live, so a direct write to it shows in ``predict()``.
+    ``refits`` and ``bisect_steps`` count the fits made and their Eq. 16
+    bisection steps.
     """
     t: float = 0.1                     # current real-time estimate (seconds)
     history_max: int = 256
     refit_every: int = 64
     blend: float = 0.5                 # weight of lognormal long-period term
-    _history: list = dataclasses.field(default_factory=list)
+    _history: collections.deque = dataclasses.field(
+        default_factory=collections.deque)
     _since_fit: int = 0
-    _lognormal: Optional[Tuple[float, float, float]] = None
+    _longterm: Optional[float] = None  # damped long-period value of the fit
+    refits: int = 0
+    bisect_steps: int = 0
+
+    def __post_init__(self) -> None:
+        self._history = collections.deque(self._history,
+                                          maxlen=self.history_max)
 
     def observe(self, t_new: float) -> float:
         self.t = adaptive_mean(self.t, t_new)
         self._history.append(float(t_new))
-        if len(self._history) > self.history_max:
-            self._history = self._history[-self.history_max:]
         self._since_fit += 1
         if self._since_fit >= self.refit_every and len(self._history) >= 8:
-            try:
-                self._lognormal = fit_lognormal3(self._history)
-            except (ValueError, FloatingPointError):
-                self._lognormal = None
+            self._refit()
             self._since_fit = 0
         return self.t
 
-    def predict(self) -> float:
-        if self._lognormal is None:
-            return self.t
-        g, mu, s2 = self._lognormal
+    def _refit(self) -> None:
+        self.refits += 1
+        try:
+            g, mu, s2, steps = _fit_lognormal3(self._history)
+        except (ValueError, FloatingPointError):
+            self._longterm = None
+            return
+        self.bisect_steps += steps
         mean = g + np.exp(mu + s2 / 2.0)
         median = g + np.exp(mu)
-        longterm = 0.5 * (mean + median)   # paper: damped long-period value
-        return (1 - self.blend) * self.t + self.blend * float(longterm)
+        # paper: damped long-period value
+        self._longterm = float(0.5 * (mean + median))
+
+    def predict(self) -> float:
+        if self._longterm is None:
+            return self.t
+        return (1 - self.blend) * self.t + self.blend * self._longterm

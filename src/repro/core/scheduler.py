@@ -5,9 +5,11 @@ Every edge device runs this scheduler.  When a detection arrives it picks
     d_i = argmin_{0 <= j <= N}  Q_j * t_j                      (Eq. 7)
 
 over all computing nodes (0 = the Cloud), using the replicated parameter
-store (queue lengths Q_j, per-item latency estimates t_j, thresholds
-alpha/beta).  Any parameter write triggers propagation to all nodes —
-mirroring the paper's SQLite + MQTT design with an in-process bus.
+store (queue lengths Q_j, per-item latency estimates t_j).  Any parameter
+write triggers propagation to all nodes — mirroring the paper's SQLite +
+MQTT design with an in-process bus.  The Eqs. 8-9 thresholds are kept by
+their users: per (query, edge) in ``repro.system.triage.TriageStage``, and
+as one single-edge state in ``repro.serving.simulator``.
 """
 from __future__ import annotations
 
@@ -15,7 +17,6 @@ import dataclasses
 from typing import Collection, Dict, List, Optional
 
 from repro.core.latency import LatencyEstimator
-from repro.core.thresholds import ThresholdState
 
 CLOUD = 0      # node id 0 is the Cloud, as in the paper
 
@@ -40,11 +41,10 @@ class NodeInfo:
 class Scheduler:
     """Per-edge-device scheduler over the shared parameter view."""
 
-    def __init__(self, nodes: List[int], interval_s: float = 1.0,
-                 thresholds: Optional[ThresholdState] = None):
-        self.nodes: Dict[int, NodeInfo] = {n: NodeInfo(n) for n in nodes}
-        self.thresholds = thresholds or ThresholdState()
-        self.interval_s = interval_s
+    def __init__(self, nodes: List[int]):
+        # held in id order: Eq. 7's scan breaks ties to the lowest id
+        self.nodes: Dict[int, NodeInfo] = {n: NodeInfo(n)
+                                           for n in sorted(nodes)}
 
     # --- Eq. 7 ---------------------------------------------------------------
     def select_node(self, exclude_cloud: bool = False,
@@ -64,8 +64,7 @@ class Scheduler:
         ``ValueError`` if the exclusions leave no eligible node.
         """
         best, best_cost = None, float("inf")
-        for nid in sorted(self.nodes):
-            n = self.nodes[nid]
+        for nid, n in self.nodes.items():
             if exclude_cloud and nid == CLOUD:
                 continue
             if nid in exclude or not n.up:
@@ -113,31 +112,11 @@ class Scheduler:
     def mark_up(self, node_id: int) -> None:
         self.nodes[node_id].up = True
 
-    # --- parameter-store updates (any write triggers threshold refresh) ------
+    # --- parameter-store updates ---------------------------------------------
     def on_enqueue(self, node_id: int) -> None:
         self.nodes[node_id].queue_len += 1
-        self._refresh_thresholds(node_id)
 
     def on_complete(self, node_id: int, latency_s: float) -> None:
         n = self.nodes[node_id]
         n.queue_len = max(0, n.queue_len - 1)
         n.estimator.observe(latency_s)
-        self._refresh_thresholds(node_id)
-
-    def _refresh_thresholds(self, node_id: int) -> None:
-        """Eqs. 8-9, driven by the updated node's drain time."""
-        n = self.nodes[node_id]
-        self.thresholds = self.thresholds.update(
-            n.queue_len, n.t, self.interval_s)
-
-    # --- cascade triage -------------------------------------------------------
-    def triage(self, confidence: float) -> str:
-        return self.thresholds.triage(confidence)
-
-    def snapshot(self) -> Dict[str, float]:
-        return {
-            "alpha": self.thresholds.alpha,
-            "beta": self.thresholds.beta,
-            **{f"Q{n.node_id}": n.queue_len for n in self.nodes.values()},
-            **{f"t{n.node_id}": n.t for n in self.nodes.values()},
-        }

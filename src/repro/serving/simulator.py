@@ -97,6 +97,30 @@ class SimResult:
         }
 
 
+class _CascadeScheduler(Scheduler):
+    """Eq. 7 scheduler plus one single-edge Eqs. 8-9 state: every queue or
+    latency write refreshes the thresholds from the updated node's drain
+    time, as the paper's parameter store does."""
+
+    def __init__(self, nodes: List[int], interval_s: float):
+        super().__init__(nodes)
+        self.interval_s = interval_s
+        self.thresholds = ThresholdState()
+
+    def on_enqueue(self, node_id: int) -> None:
+        super().on_enqueue(node_id)
+        self._refresh_thresholds(node_id)
+
+    def on_complete(self, node_id: int, latency_s: float) -> None:
+        super().on_complete(node_id, latency_s)
+        self._refresh_thresholds(node_id)
+
+    def _refresh_thresholds(self, node_id: int) -> None:
+        n = self.nodes[node_id]
+        self.thresholds = self.thresholds.update(
+            n.queue_len, n.t, self.interval_s)
+
+
 class CloudEdgeSim:
     """Discrete-event simulation of N edge nodes + 1 cloud node."""
 
@@ -114,9 +138,7 @@ class CloudEdgeSim:
             self.specs[e.node_id] = e
         self.bus = Bus()
         self.db = ParamDB(self.bus)
-        self.sched = Scheduler(sorted(self.specs),
-                               interval_s=interval_s,
-                               thresholds=ThresholdState())
+        self.sched = _CascadeScheduler(sorted(self.specs), interval_s)
         if scheme == "surveiledge_fixed":
             # frozen at the paper's constants: alpha=0.8, beta=0.1 (or a
             # caller-supplied pair, for the threshold-ablation benchmark)

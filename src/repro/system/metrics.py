@@ -203,6 +203,9 @@ class QueryReport:
     #                                        driver pays one per boundary-free
     #                                        run — their ratio is the
     #                                        host-loop reduction factor)
+    # --- Eq. 7 latency estimators, summed over nodes ---------------------------
+    estimator_refits: int = 0              # lognormal refits (Eqs. 10-16)
+    refit_bisect_steps: int = 0            # Eq. 16 bisection steps of them
     # streaming aggregates (Scenario.metrics_window_s): when set, the
     # per-item arrays above are EMPTY and every metric below reads the
     # O(window) cells instead — city-of-cameras runs must not hold (or

@@ -189,8 +189,7 @@ class QueryPipeline:
                 raise ValueError(
                     f"scenario {sc.name!r}: failure at t={t_fail} references "
                     f"node {nid}, but failable edges are {list(sc.edge_ids)}")
-        self.sched = Scheduler(sorted(self.service_s),
-                               interval_s=sc.interval_s)
+        self.sched = Scheduler(sorted(self.service_s))
         self.bus = Bus()
         self.db = ParamDB(self.bus)
         for nid, svc in self.service_s.items():
@@ -976,6 +975,10 @@ class QueryPipeline:
             escalated=self._escalated,
             rerouted=self._rerouted,
             kernel_launches=self.triage_stage.launches,
+            estimator_refits=sum(n.estimator.refits
+                                 for n in self.sched.nodes.values()),
+            refit_bisect_steps=sum(n.estimator.bisect_steps
+                                   for n in self.sched.nodes.values()),
             supersteps=self.superstep.supersteps,
             triaged_ticks=self._triaged_ticks,
             stream=self._agg,
