@@ -1,7 +1,8 @@
 """The full moving-object detection stage (paper §IV-C), end to end.
 
 frames -> fused pixel cascade (ONE Pallas launch: framediff + dilate +
-erode + foreground count) -> one jitted program: CCL, a fixed table of
+erode + foreground count) -> one jitted program: CCL (ONE Pallas launch
+of the label fixpoint, ``kernels/ccl.py``), a fixed table of
 filtered bounding boxes per camera, their crops of the middle frame and,
 for a classifier's vocabulary, the crops' patch tokens -> the host gets
 the table.
@@ -61,13 +62,16 @@ def _split(frames: jax.Array):
 
 
 @functools.partial(jax.jit, static_argnames=("min_area", "crop",
-                                             "max_boxes", "vocab_size"))
+                                             "max_boxes", "vocab_size",
+                                             "use_pallas"))
 def _boxes_and_crops(mask: jax.Array, middle: jax.Array, *, min_area: int,
-                     crop: int, max_boxes: int, vocab_size: Optional[int]):
-    """One tick's device work after the cascade: labels, the box table,
-    the crops centred on each box (shifted inside the frame) and their
-    tokens."""
-    labels = components.label_components(mask)
+                     crop: int, max_boxes: int, vocab_size: Optional[int],
+                     use_pallas: bool):
+    """One tick's device work after the cascade: labels (and each
+    camera's fixpoint steps), the box table, the crops centred on each
+    box (shifted inside the frame) and their tokens."""
+    with jax.named_scope("ccl"):
+        labels, steps = ops.label_components(mask, use_pallas=use_pallas)
     boxes, keep, comps = components.box_table(labels, max_boxes,
                                               min_area=min_area)
     H, W = middle.shape[1], middle.shape[2]
@@ -83,7 +87,7 @@ def _boxes_and_crops(mask: jax.Array, middle: jax.Array, *, min_area: int,
         crops = jax.vmap(jax.vmap(cut, in_axes=(None, 0, 0)))(
             middle, top, left).astype(jnp.uint8)      # (B, K, crop, crop, 3)
     out = {"boxes": boxes, "keep": keep, "components": comps,
-           "crops": crops}
+           "crops": crops, "steps": steps}
     if vocab_size is not None:
         B, K = keep.shape
         tokens, tie = SV.crops_to_tokens_device(
@@ -106,8 +110,10 @@ def detect(frames, *, threshold: int = 40, crop: int = 32,
     ``vocab_size`` each detection carries its crop's patch tokens
     (``crops_to_tokens``: made on the device, near ties redone on the host
     in float64).  ``counters``, when given, gains ``boxes_dropped``
-    (components past a camera's ``MAX_BOXES``) and
-    ``motionless_camera_ticks`` (cameras with an empty mask).
+    (components past a camera's ``MAX_BOXES``),
+    ``motionless_camera_ticks`` (cameras with an empty mask), and, on
+    ticks that run the CCL program, ``ccl_cameras`` (the cameras it
+    labels) and ``ccl_steps`` (their label fixpoint steps, summed).
     """
     spans = spans if spans is not None else Spans()
     with spans.span("detect.cascade"):
@@ -128,11 +134,14 @@ def detect(frames, *, threshold: int = 40, crop: int = 32,
     with spans.span("detect.ccl"):
         table = jax.device_get(_boxes_and_crops(
             mask, f1, min_area=min_area, crop=crop, max_boxes=MAX_BOXES,
-            vocab_size=vocab_size))
+            vocab_size=vocab_size, use_pallas=use_pallas))
     with spans.span("detect.boxes"):
         if counters is not None:
-            counters["boxes_dropped"] = counters.get("boxes_dropped", 0) \
-                + int(np.maximum(table["components"] - MAX_BOXES, 0).sum())
+            dropped = np.maximum(table["components"] - MAX_BOXES, 0).sum()
+            for key, n in (("boxes_dropped", dropped),
+                           ("ccl_steps", table["steps"].sum()),
+                           ("ccl_cameras", B)):
+                counters[key] = counters.get(key, 0) + int(n)
         tokens = table.get("tokens")
         if tokens is not None:
             tie = table["tie"] & table["keep"]

@@ -13,8 +13,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.detection import components as _cc
 from repro.kernels import buckets as _bk
 from repro.kernels import calibrate as _ca
+from repro.kernels import ccl as _ccl
 from repro.kernels import flash_attention as _fa
 from repro.kernels import framediff as _fd
 from repro.kernels import morphology as _mo
@@ -102,6 +104,25 @@ def pixel_cascade(f0: jax.Array, f1: jax.Array, f2: jax.Array, *,
         mask = erode3x3(dilate3x3(framediff(
             f0, f1, f2, threshold=threshold, maxval=maxval)), maxval=maxval)
     return mask, jnp.sum(mask > 0, axis=(1, 2)).astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("use_pallas",))
+def label_components(mask: jax.Array, use_pallas: bool = True):
+    """Connected-component labels of a (B, H, W) mask, 8-connected:
+    ``(labels (B, H, W) int32, steps (B,) int32)``, -1 for background and
+    the min linear index ``y * W + x`` of its component for foreground;
+    ``steps[b]`` counts camera b's fixpoint steps.
+
+    One launch labels every camera, each in VMEM from its mask to its
+    own convergence (``kernels/ccl.py``), when the frame's planes fit in
+    VMEM (``ccl.fits``); otherwise, and with ``use_pallas=False``, the
+    jnp fixpoint ``components.label_components`` runs, the oracle the
+    kernel is tested bit-exact against.
+    """
+    H, W = mask.shape[1], mask.shape[2]
+    if use_pallas and _ccl.fits(H, W):
+        return _ccl.label_components_pallas(mask)
+    return _cc.label_components(mask)
 
 
 @functools.partial(jax.jit,

@@ -37,7 +37,8 @@ their totals surface in ``QueryReport.stage_timings`` as ``render_s``,
 ``framediff_s`` and ``classify_s``, and ``detect``'s three parts as
 ``detect_cascade_s``, ``detect_ccl_s`` and ``detect_boxes_s``.  The
 frontend's counters (``crops_scored``, ``crop_pad_rows``,
-``motionless_camera_ticks``, ``boxes_dropped``) join the report's summary.
+``motionless_camera_ticks``, ``boxes_dropped``, ``ccl_steps``,
+``ccl_cameras``) join the report's summary.
 
 By default the classifier is a freshly initialized (untrained) CQ edge
 model — the full compute path with no training in the loop, for tests and
@@ -79,7 +80,7 @@ DETECT_PARTS = ("cascade", "ccl", "boxes")
 
 #: the frontend's counters, summed over a stream's ticks
 COUNTERS = ("crops_scored", "crop_pad_rows", "motionless_camera_ticks",
-            "boxes_dropped")
+            "boxes_dropped", "ccl_steps", "ccl_cameras")
 
 
 def match_truth(box: Box, truth: SV.FrameTruth,

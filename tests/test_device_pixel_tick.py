@@ -115,7 +115,10 @@ def test_detect_on_device_frames_matches_both_references():
     m_ref, c_ref = R.pixel_cascade(fr[:, 0], fr[:, 1], fr[:, 2], 40)
     np.testing.assert_array_equal(np.asarray(mask), m_ref)
     np.testing.assert_array_equal(np.asarray(counts), c_ref)
-    assert counters == {"motionless_camera_ticks": 0, "boxes_dropped": 0}
+    _, steps = ops.label_components(mask)
+    assert counters == {"motionless_camera_ticks": 0, "boxes_dropped": 0,
+                        "ccl_cameras": len(cams),
+                        "ccl_steps": int(np.asarray(steps).sum())}
     n = 0
     for b, per in enumerate(dets):
         ref_boxes = R.boxes(m_ref[b], 12)
@@ -180,7 +183,7 @@ def test_box_table_matches_reference_in_window_and_whole_frame(big):
     if big:
         m[1, 10:90, 100:120] = 255       # taller than the window
         m[2, 50:54, 20:240] = 255        # wider than the window
-    lab = components.label_components(jnp.asarray(m))
+    lab, _ = components.label_components(jnp.asarray(m))
     boxes, keep, comps = (np.asarray(a) for a in jax.jit(
         functools.partial(components.box_table, max_boxes=32,
                           min_area=12))(lab))

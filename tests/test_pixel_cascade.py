@@ -17,7 +17,7 @@ import pytest
 from jax.experimental import pallas as pallas_mod
 
 from repro.data import synthetic_video as SV
-from repro.detection import components, pipeline as DP
+from repro.detection import pipeline as DP
 from repro.kernels import ops, ref
 from repro.kernels import pixel_cascade as PC
 from repro.kernels.buckets import (MAX_FRAME_ELEMS, MIN_FRAME_SIDE,
@@ -163,7 +163,8 @@ def test_pixel_tick_launch_budget(monkeypatch):
 
 def test_pixel_city_tick_detect_launch_budget(monkeypatch):
     """End-to-end: a pixel_city-style fleet tick through ``detect`` stays
-    within the <= 2 Pallas-launch budget on the fused path."""
+    within the <= 2 Pallas-launch budget on the fused path: the cascade
+    and the label fixpoint."""
     launches = {"n": 0}
     real = pallas_mod.pallas_call
 
@@ -180,7 +181,7 @@ def test_pixel_city_tick_detect_launch_budget(monkeypatch):
     assert (cam.height, cam.width) == (96, 128)   # city preset sanity
     launches["n"] = 0
     DP.detect(batch, threshold=40, fused=True)
-    assert launches["n"] <= 2
+    assert launches["n"] == 2
 
 
 # --- detect integration ------------------------------------------------------
@@ -202,19 +203,25 @@ def test_detect_fused_matches_staged_end_to_end():
 
 def test_static_scene_skips_ccl(monkeypatch):
     """A motionless tick returns empties WITHOUT running the CCL
-    fixpoint — the fused kernel's counts short-circuit it."""
+    fixpoint — the fused kernel's counts short-circuit it.  A moving tick
+    at a never-traced shape does reach the patched labeller, so the patch
+    sits where the labelling happens."""
     called = {"n": 0}
-    real = components.label_components
+    real = ops.label_components
 
-    def counting(mask):
+    def counting(mask, **kw):
         called["n"] += 1
-        return real(mask)
+        return real(mask, **kw)
 
-    monkeypatch.setattr(components, "label_components", counting)
+    monkeypatch.setattr(ops, "label_components", counting)
     static = np.full((2, 3, 96, 128, 3), 55, np.int32)
     out = DP.detect(static, fused=True)
     assert out == [[], []]
     assert called["n"] == 0
+    moving = np.random.default_rng(3).integers(
+        0, 256, (2, 3, 59, 127, 3)).astype(np.int32)
+    DP.detect(moving, fused=True)
+    assert called["n"] == 1
 
 
 # --- Scenario.frame_hw validation -------------------------------------------

@@ -16,6 +16,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import calibrate as CA
+from repro.kernels import ccl as CCL
 from repro.kernels import pixel_cascade as PC
 from repro.kernels import similarity as SIM
 from repro.kernels import triage as TR
@@ -55,6 +56,15 @@ def test_pixel_cascade_compiles(one_chip, B, H, W):
 
     frame = ((B, H, W, 3), jnp.uint8)
     assert "tpu_custom_call" in _compile(fn, one_chip, frame, frame, frame)
+
+
+@pytest.mark.parametrize("B,H,W", [(12, 96, 128), (24, 540, 960),
+                                   (4, 1080, 1920)])
+def test_ccl_fixpoint_compiles(one_chip, B, H, W):
+    """The label fixpoint with one camera's planes resident in VMEM: the
+    default frame, the 960x540 fleet and a 1080p frame (8.4 MB a plane)."""
+    fn = functools.partial(CCL.label_components_pallas, interpret=False)
+    assert "tpu_custom_call" in _compile(fn, one_chip, ((B, H, W), jnp.int32))
 
 
 @pytest.mark.parametrize("E,N", [(64, 512), (8192, 512), (1 << 19, 8)])
