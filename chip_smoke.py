@@ -112,8 +112,8 @@ def _exact(what, got, want):
 
 def check_pixel(cap):
     (f0, f1, f2), kw = cap.best[1], cap.best[2]
-    _require(kw.get("use_pallas", True) and kw.get("fused", True),
-             f"pixel path left the fused Pallas kernel: {kw}")
+    _require(kw.get("use_pallas", True),
+             f"pixel path left the Pallas kernel: {kw}")
     mask, counts = ops.pixel_cascade(f0, f1, f2, threshold=kw["threshold"])
     want_mask, want_counts = ref.pixel_cascade_np(
         np.asarray(f0), np.asarray(f1), np.asarray(f2), kw["threshold"])

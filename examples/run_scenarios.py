@@ -3,7 +3,7 @@
 Each scenario is simulated under all four query schemes.  The default
 frontend is the model-free synthetic confidence stream (fast; no model in
 the loop); ``--frontend pixel`` runs the paper's full pixel path instead
-(rendered frames -> Pallas framediff/morphology -> motion crops -> CQ
+(rendered frames -> Pallas pixel cascade -> motion crops -> CQ
 scores).  For the CQ-model-scored workload, see
 ``benchmarks/table2_single_edge.py`` etc.
 

@@ -5,10 +5,10 @@ pointer-chasing with no TPU analogue.  We use iterative min-label propagation
 (a data-parallel fixpoint: every foreground pixel takes the min label of its
 8-neighbourhood until convergence), which yields identical bounding boxes for
 the pipeline's purpose; see the detection stage in ``docs/ARCHITECTURE.md``.
-``label_components`` here is the jnp fixpoint, the oracle of the Pallas
-kernel that the served path runs (``kernels/ccl.py`` via
-``ops.label_components``).  ``box_table`` turns the labels into a
-fixed-size table of filtered boxes per camera, also on the device.
+The labels come from ``ops.label_components`` (the Pallas kernel of
+``kernels/ccl.py``, or the jnp fixpoint beside it); ``box_table`` here
+turns them into a fixed-size table of filtered boxes per camera, also on
+the device.
 """
 from __future__ import annotations
 
@@ -17,57 +17,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-
-BIG = jnp.int32(1 << 30)
-
-#: neighbourhood-min steps between two convergence checks of the fixpoint
-STEPS = 4
-
-
-def label_components(mask: jax.Array, max_iters: int = 256
-                     ) -> Tuple[jax.Array, jax.Array]:
-    """mask (B,H,W) {0, nonzero} -> (labels (B,H,W) int32 (-1 background),
-    steps (B,) int32).
-
-    Label of a component = min linear index of its pixels.  Each step
-    takes the min over a pixel's 3x3 neighbourhood, as a min over each
-    row's three then over three rows (slices of BIG-padded copies);
-    convergence is checked every ``STEPS`` steps, and at most
-    ``max_iters`` steps run.  Each camera stops at its own convergence
-    (the loop is vmapped over cameras), and ``steps`` counts its steps,
-    ``STEPS`` to a check, the final check included.  This is the oracle
-    of ``kernels/ccl.py`` (``ops.label_components``).
-    """
-    _, H, W = mask.shape
-    lin = jnp.arange(H * W, dtype=jnp.int32).reshape(H, W)
-
-    def one(m):
-        fg = m > 0
-
-        def nb_min(lab):
-            pad = jnp.pad(lab, ((0, 0), (1, 1)), constant_values=BIG)
-            row = jnp.minimum(jnp.minimum(pad[:, :W], pad[:, 2:]), lab)
-            pad = jnp.pad(row, ((1, 1), (0, 0)), constant_values=BIG)
-            m = jnp.minimum(jnp.minimum(pad[:H], pad[2:]), row)
-            return jnp.where(fg, m, BIG)
-
-        def cond(state):
-            lab, changed, it = state
-            return changed & (it < max_iters)
-
-        def body(state):
-            lab, _, it = state
-            new = lab
-            for _ in range(STEPS):
-                new = nb_min(new)
-            return new, jnp.any(new != lab), it + STEPS
-
-        lab, _, it = jax.lax.while_loop(
-            cond, body, (jnp.where(fg, lin, BIG), jnp.bool_(True),
-                         jnp.int32(0)))
-        return jnp.where(fg, lab, -1), it
-
-    return jax.vmap(one)(mask)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,8 +58,8 @@ def _stats(same, y0, x0):
 def box_table(labels: jax.Array, max_boxes: int, *, min_area: int = 12,
               max_aspect: float = 6.0
               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Fixed-shape bounding boxes of ``label_components``' labels, on the
-    device, with the paper's size/aspect filter (§IV-C).
+    """Fixed-shape bounding boxes of ``ops.label_components``' labels, on
+    the device, with the paper's size/aspect filter (§IV-C).
 
     labels (B, H, W) -> (boxes (B, K, 5) int32 rows of (y0, x0, y1, x1,
     area), keep (B, K) bool, components (B,) int32), K = ``max_boxes``.

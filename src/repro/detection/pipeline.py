@@ -10,9 +10,8 @@ the table.
 Frames may live on the device; nothing but the cascade's per-camera
 counts, the box table and its crops (and tokens) comes back.  The counts
 skip the CCL program on motionless ticks, and motionless cameras yield no
-boxes.  ``fused=False`` keeps the original staged chain — three separate
-Pallas launches — as the differential reference; ``use_pallas=False``
-drops to the jnp oracle.
+boxes.  ``use_pallas=False`` drops to the jnp references
+(``ref.pixel_cascade_ref`` and the jnp label fixpoint).
 
 Under a call's ``Spans`` the stage splits into ``detect.cascade`` (the
 cascade launch and its counts), ``detect.ccl`` (the CCL program and the
@@ -48,11 +47,10 @@ class Detection:
 
 
 def motion_mask(f0: jax.Array, f1: jax.Array, f2: jax.Array, *,
-                threshold: int = 40, use_pallas: bool = True,
-                fused: bool = True) -> jax.Array:
+                threshold: int = 40, use_pallas: bool = True) -> jax.Array:
     """Eqs. 1-6: framediff + dilate + erode.  (B,H,W,3)x3 -> (B,H,W)."""
     mask, _ = ops.pixel_cascade(f0, f1, f2, threshold=threshold,
-                                use_pallas=use_pallas, fused=fused)
+                                use_pallas=use_pallas)
     return mask
 
 
@@ -98,7 +96,7 @@ def _boxes_and_crops(mask: jax.Array, middle: jax.Array, *, min_area: int,
 
 
 def detect(frames, *, threshold: int = 40, crop: int = 32,
-           min_area: int = 12, use_pallas: bool = True, fused: bool = True,
+           min_area: int = 12, use_pallas: bool = True,
            vocab_size: Optional[int] = None, spans: Optional[Spans] = None,
            counters: Optional[Dict[str, int]] = None
            ) -> List[List[Detection]]:
@@ -123,7 +121,7 @@ def detect(frames, *, threshold: int = 40, crop: int = 32,
         B = frames.shape[0]
         f0, f1, f2 = _split(frames)
         mask, counts = ops.pixel_cascade(f0, f1, f2, threshold=threshold,
-                                         use_pallas=use_pallas, fused=fused)
+                                         use_pallas=use_pallas)
         moving = np.asarray(counts) > 0
         if counters is not None:
             counters["motionless_camera_ticks"] = counters.get(

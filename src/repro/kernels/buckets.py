@@ -35,7 +35,7 @@ MAX_SUPERSTEP_ELEMS = 1 << 22
 # --- pixel-cascade frame tiles ------------------------------------------------
 # The fused pixel-cascade kernel (``kernels/pixel_cascade.py``) walks each
 # camera's frame in (FRAME_BAND_H, W) row bands with the W axis padded to
-# lane multiples; the staged morphology kernels use the same band height.
+# lane multiples.
 # These are the numbers ``validate_frame_hw`` checks a Scenario.frame_hw
 # against, so a bad frame size raises here — with the padded tile spelled
 # out — instead of as a Pallas block-shape error at first render.
@@ -55,7 +55,7 @@ MAX_FRAME_ELEMS = 1 << 22
 
 
 def frame_pad(h: int, w: int):
-    """Padded (H, W) the pixel kernels actually launch for a (h, w) frame."""
+    """Padded (H, W) the pixel cascade actually launches for a (h, w) frame."""
     hp = -(-h // FRAME_BAND_H) * FRAME_BAND_H
     wp = -(-w // FRAME_LANE_W) * FRAME_LANE_W
     return hp, wp

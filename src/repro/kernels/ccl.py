@@ -1,8 +1,11 @@
-"""Pallas TPU kernel: the connected-component label fixpoint, per camera.
+"""Connected-component label fixpoint, per camera: a Pallas TPU kernel
+and the jnp fixpoint it falls back to.
 
-``components.label_components`` is an XLA ``while_loop`` over the whole
-fleet's (B, H, W) label plane: every step streams the plane through HBM,
-and the loop runs until the slowest camera has converged.  This kernel
+``label_components`` is the jnp fixpoint, an XLA ``while_loop`` over the
+whole fleet's (B, H, W) label plane: every step streams the plane through
+HBM, and the loop runs until the slowest camera has converged.  It runs
+where a frame's planes do not fit in VMEM (``fits``) and is the oracle
+the kernel is tested bit-exact against.  The kernel
 runs the same fixpoint with one camera's whole (padded) frame resident in
 VMEM from its initial labels to convergence: the grid walks the cameras,
 the mask is read once and the labels are written once, and each camera
@@ -28,7 +31,7 @@ exactly when its first step does: the change is checked on that first
 step, and a block whose first step changes nothing computes no more.
 Min is associative and commutative, so each camera's own fixpoint is
 the one the fleet-wide loop reaches: the labels are bit-exact with
-``components.label_components``.
+``label_components``.
 
 Compiled on TPU, interpreted on CPU (``runtime.resolve_interpret``).
 """
@@ -42,11 +45,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.detection import components
 from repro.kernels.runtime import resolve_interpret
 
-#: the jnp fixpoint's background label and steps between two checks
-BIG, STEPS = int(components.BIG), components.STEPS
+#: background label, above any pixel's linear index, which no min lowers
+BIG = 1 << 30
+
+#: neighbourhood-min steps between two convergence checks of the fixpoint
+STEPS = 4
 
 #: background rows above and below the frame in each label plane: one
 #: sublane tile, so every strip window starts on a tile
@@ -59,6 +64,52 @@ MAX_STRIP = 128
 #: v5e VMEM a launch may claim (of 128 MiB a core); a frame whose
 #: resident planes need more keeps the jnp fixpoint
 VMEM_BUDGET = 100 << 20
+
+
+def label_components(mask: jax.Array, max_iters: int = 256
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """mask (B,H,W) {0, nonzero} -> (labels (B,H,W) int32 (-1 background),
+    steps (B,) int32).
+
+    Label of a component = min linear index of its pixels.  Each step
+    takes the min over a pixel's 3x3 neighbourhood, as a min over each
+    row's three then over three rows (slices of BIG-padded copies);
+    convergence is checked every ``STEPS`` steps, and at most
+    ``max_iters`` steps run.  Each camera stops at its own convergence
+    (the loop is vmapped over cameras), and ``steps`` counts its steps,
+    ``STEPS`` to a check, the final check included.
+    """
+    _, H, W = mask.shape
+    lin = jnp.arange(H * W, dtype=jnp.int32).reshape(H, W)
+    big = jnp.int32(BIG)
+
+    def one(m):
+        fg = m > 0
+
+        def nb_min(lab):
+            pad = jnp.pad(lab, ((0, 0), (1, 1)), constant_values=big)
+            row = jnp.minimum(jnp.minimum(pad[:, :W], pad[:, 2:]), lab)
+            pad = jnp.pad(row, ((1, 1), (0, 0)), constant_values=big)
+            m = jnp.minimum(jnp.minimum(pad[:H], pad[2:]), row)
+            return jnp.where(fg, m, big)
+
+        def cond(state):
+            lab, changed, it = state
+            return changed & (it < max_iters)
+
+        def body(state):
+            lab, _, it = state
+            new = lab
+            for _ in range(STEPS):
+                new = nb_min(new)
+            return new, jnp.any(new != lab), it + STEPS
+
+        lab, _, it = jax.lax.while_loop(
+            cond, body, (jnp.where(fg, lin, big), jnp.bool_(True),
+                         jnp.int32(0)))
+        return jnp.where(fg, lab, -1), it
+
+    return jax.vmap(one)(mask)
 
 
 def plane_shape(h: int, w: int) -> Tuple[int, int, int]:
@@ -84,7 +135,7 @@ def vmem_bytes(h: int, w: int) -> int:
 
 def fits(h: int, w: int) -> bool:
     """Whether an (h, w) frame's fixpoint runs in the kernel; a larger
-    one keeps the jnp fixpoint."""
+    one keeps the jnp fixpoint, ``label_components``."""
     return vmem_bytes(h, w) <= VMEM_BUDGET
 
 

@@ -7,73 +7,26 @@ backend and interprets on the CPU one.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.detection import components as _cc
 from repro.kernels import buckets as _bk
 from repro.kernels import calibrate as _ca
 from repro.kernels import ccl as _ccl
 from repro.kernels import flash_attention as _fa
-from repro.kernels import framediff as _fd
-from repro.kernels import morphology as _mo
 from repro.kernels import pixel_cascade as _pc
 from repro.kernels import similarity as _sim
 from repro.kernels import triage as _tr
 from repro.kernels import ref as _ref
 
 
-def _pad_hw(x: jax.Array, mh: int, mw: int, value=0) -> Tuple[jax.Array, int, int]:
-    H, W = x.shape[1], x.shape[2]
-    ph = (-H) % mh
-    pw = (-W) % mw
-    if ph or pw:
-        pad = [(0, 0), (0, ph), (0, pw)] + [(0, 0)] * (x.ndim - 3)
-        x = jnp.pad(x, pad, constant_values=value)
-    return x, H, W
-
-
-@functools.partial(jax.jit, static_argnames=("threshold", "maxval", "use_pallas"))
-def framediff(f0: jax.Array, f1: jax.Array, f2: jax.Array, *,
-              threshold: int = 40, maxval: int = 255,
-              use_pallas: bool = True) -> jax.Array:
-    """Binary motion mask from 3 consecutive frames (B,H,W,3) uint8/int."""
-    f0, f1, f2 = (x.astype(jnp.int32) for x in (f0, f1, f2))
-    if not use_pallas:
-        return _ref.framediff_ref(f0, f1, f2, threshold, maxval)
-    f0p, H, W = _pad_hw(f0, _fd.BLOCK_H, _fd.BLOCK_W)
-    f1p, _, _ = _pad_hw(f1, _fd.BLOCK_H, _fd.BLOCK_W)
-    f2p, _, _ = _pad_hw(f2, _fd.BLOCK_H, _fd.BLOCK_W)
-    out = _fd.framediff_pallas(f0p, f1p, f2p, threshold=threshold,
-                               maxval=maxval)
-    return out[:, :H, :W]
-
-
-@functools.partial(jax.jit, static_argnames=("use_pallas",))
-def dilate3x3(x: jax.Array, use_pallas: bool = True) -> jax.Array:
-    x = x.astype(jnp.int32)
-    if not use_pallas:
-        return _ref.dilate3x3_ref(x)
-    return _mo.dilate3x3_pallas(x)
-
-
-@functools.partial(jax.jit, static_argnames=("maxval", "use_pallas"))
-def erode3x3(x: jax.Array, maxval: int = 255, use_pallas: bool = True) -> jax.Array:
-    x = x.astype(jnp.int32)
-    if not use_pallas:
-        return _ref.erode3x3_ref(x, maxval)
-    return _mo.erode3x3_pallas(x, maxval=maxval)
-
-
 @functools.partial(jax.jit,
-                   static_argnames=("threshold", "maxval", "use_pallas",
-                                    "fused"))
+                   static_argnames=("threshold", "maxval", "use_pallas"))
 def pixel_cascade(f0: jax.Array, f1: jax.Array, f2: jax.Array, *,
                   threshold: int = 40, maxval: int = 255,
-                  use_pallas: bool = True, fused: bool = True):
+                  use_pallas: bool = True):
     """Whole pixel frontend — framediff → dilate → erode → count — in ONE
     Pallas launch per tick.
 
@@ -82,28 +35,22 @@ def pixel_cascade(f0: jax.Array, f1: jax.Array, f2: jax.Array, *,
     count — the reduction ``detect`` uses to skip connected-component
     labeling for motionless cameras without a second pass over the mask.
 
-    ``fused=False`` (or ``use_pallas=False``) runs the staged chain — the
-    original three separate launches (or the jnp reference twin) plus a
-    mask reduction — retained as the differential reference the fused
-    kernel is tested bit-exact against.  Frames are zero-padded to the
+    ``use_pallas=False`` runs the jnp reference ``ref.pixel_cascade_ref``
+    plus a mask reduction.  Frames are zero-padded to the
     (FRAME_BAND_H, FRAME_LANE_W) tile from ``kernels/buckets.py`` before
-    the fused launch, with the colour channels moved to a leading plane
-    axis; the pad is sliced back off and never reaches counts.
+    the launch, with the colour channels moved to a leading plane axis;
+    the pad is sliced back off and never reaches counts.
     """
     f0, f1, f2 = (x.astype(jnp.int32) for x in (f0, f1, f2))
-    if use_pallas and fused:
-        H, W = f0.shape[1], f0.shape[2]
-        with jax.named_scope("pixel_cascade"):
-            mask, counts = _pc.pixel_cascade_pallas(
-                *(_pc.planar_frames(x) for x in (f0, f1, f2)),
-                threshold=threshold, maxval=maxval, true_hw=(H, W))
-        return mask[:, :H, :W], counts
     if not use_pallas:
         mask = _ref.pixel_cascade_ref(f0, f1, f2, threshold, maxval)
-    else:
-        mask = erode3x3(dilate3x3(framediff(
-            f0, f1, f2, threshold=threshold, maxval=maxval)), maxval=maxval)
-    return mask, jnp.sum(mask > 0, axis=(1, 2)).astype(jnp.int32)
+        return mask, jnp.sum(mask > 0, axis=(1, 2)).astype(jnp.int32)
+    H, W = f0.shape[1], f0.shape[2]
+    with jax.named_scope("pixel_cascade"):
+        mask, counts = _pc.pixel_cascade_pallas(
+            *(_pc.planar_frames(x) for x in (f0, f1, f2)),
+            threshold=threshold, maxval=maxval, true_hw=(H, W))
+    return mask[:, :H, :W], counts
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
@@ -116,13 +63,13 @@ def label_components(mask: jax.Array, use_pallas: bool = True):
     One launch labels every camera, each in VMEM from its mask to its
     own convergence (``kernels/ccl.py``), when the frame's planes fit in
     VMEM (``ccl.fits``); otherwise, and with ``use_pallas=False``, the
-    jnp fixpoint ``components.label_components`` runs, the oracle the
-    kernel is tested bit-exact against.
+    jnp fixpoint ``ccl.label_components`` runs, the oracle the kernel is
+    tested bit-exact against.
     """
     H, W = mask.shape[1], mask.shape[2]
     if use_pallas and _ccl.fits(H, W):
         return _ccl.label_components_pallas(mask)
-    return _cc.label_components(mask)
+    return _ccl.label_components(mask)
 
 
 @functools.partial(jax.jit,
@@ -153,51 +100,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                                      block_q=min(block_q, qp.shape[2]),
                                      block_k=min(block_k, kp.shape[2]))
     return out[:, :, :Sq]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("alpha", "beta", "capacity", "use_pallas"))
-def triage(conf: jax.Array, *, alpha: float, beta: float, capacity: int,
-           use_pallas: bool = True):
-    """(N,) confidences -> (routes, slots, count)."""
-    conf = conf.astype(jnp.float32)
-    if not use_pallas:
-        return _ref.triage_ref(conf, alpha, beta, capacity)
-    routes, slots, count = _tr.triage_pallas(
-        conf, alpha=alpha, beta=beta, capacity=capacity)
-    return routes, slots, count[0]
-
-
-@functools.partial(jax.jit, static_argnames=("capacity", "use_pallas"))
-def _triage_dynamic(conf: jax.Array, thresholds: jax.Array, *, capacity: int,
-                    use_pallas: bool = True):
-    if not use_pallas:
-        return _ref.triage_ref(conf, thresholds[0], thresholds[1], capacity)
-    routes, slots, count = _tr.triage_dynamic_pallas(
-        conf, thresholds, capacity=capacity)
-    return routes, slots, count[0]
-
-
-def triage_batched(conf: jax.Array, *, alpha: float, beta: float,
-                   capacity: int, use_pallas: bool = True):
-    """Per-tick batched triage with *runtime* thresholds.
-
-    Pads N up to a power-of-two bucket (min 8) before the single kernel
-    launch, then slices the pad back off, so a stream of tick batches of
-    varying size hits a handful of cached compilations — and the adaptive
-    alpha/beta (which change on every Eqs. 8-9 update) are data, not trace
-    constants.  Pad lanes use conf=-1.0, which always routes to 'reject'
-    (beta >= 0) and therefore can never claim an escalation slot or count.
-    """
-    conf = jnp.asarray(conf, jnp.float32)
-    (n,) = conf.shape
-    bucket = _bucket(n)
-    if bucket != n:
-        conf = jnp.pad(conf, (0, bucket - n), constant_values=-1.0)
-    thresholds = jnp.asarray([alpha, beta], jnp.float32)
-    routes, slots, count = _triage_dynamic(
-        conf, thresholds, capacity=capacity, use_pallas=use_pallas)
-    return routes[:n], slots[:n], count
 
 
 # padding-bucket arithmetic lives in ``kernels/buckets.py`` (jax-free, so

@@ -1,7 +1,7 @@
 """Pallas TPU kernel: the fused pixel-cascade frontend (paper Eqs. 1-6).
 
-One launch replaces the per-tick chain that used to cost three Pallas
-programs plus two full-frame HBM round-trips:
+One launch per tick runs the whole chain, with no full-frame HBM
+round-trip between its stages:
 
     framediff (Eqs. 1-4) -> 3x3 dilate (Eq. 5) -> 3x3 erode (Eq. 6)
                          -> per-band foreground reduction
@@ -41,16 +41,15 @@ without paying another device pass over the mask.  Its (1, 1, 1) block
 is the whole trailing extent of a (B, 1, 1) array, which keeps it
 lane-legal, and it stays resident while the camera's bands accumulate.
 
-Boundary semantics match the staged chain bit-exactly: framediff outside
-the true (H, W) image is 0 (dilate's fill), dilated values outside it are
-``maxval`` (erode's fill), and the final mask is zeroed outside the true
-image so the pad region can never contribute to a count.  The stencil
-math itself is ``morphology.stencil3x3`` — the same nine-shift reduction
-the staged kernels run, one implementation for both paths.
+Boundary semantics match the separate framediff, dilate and erode of
+``ref.pixel_cascade_ref`` bit-exactly: framediff outside the true (H, W)
+image is 0 (dilate's fill), dilated values outside it are ``maxval``
+(erode's fill), and the final mask is zeroed outside the true image so
+the pad region can never contribute to a count.  Both stencils are
+``stencil3x3``, a nine-shift reduction in registers.
 
 Compiled on TPU, interpreted on CPU (``runtime.resolve_interpret``);
-validated against the staged kernels and the independent NumPy oracle
-``ref.pixel_cascade_np``.
+validated against the independent NumPy oracle ``ref.pixel_cascade_np``.
 """
 from __future__ import annotations
 
@@ -63,10 +62,31 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.buckets import FRAME_BAND_H, FRAME_LANE_W, frame_pad
-from repro.kernels.morphology import stencil3x3
 from repro.kernels.runtime import resolve_interpret
 
 BAND_H = FRAME_BAND_H
+
+_OPS = {"max": jnp.maximum, "min": jnp.minimum}
+
+
+def stencil3x3(win: jax.Array, *, op: str, fill: int,
+               out_h: int, out_w: int) -> jax.Array:
+    """Nine-shift 3x3 stencil reduce over a row window, in registers.
+
+    ``win`` is an (out_h + 2, out_w) window that already carries the
+    1-row halo above and below; the 1-column halo is filled here with
+    ``fill`` (a concatenate, not a host pad), so callers never pad W.
+    Returns the (out_h, out_w) reduced block.
+    """
+    red = _OPS[op]
+    fc = jnp.full((win.shape[0], 1), fill, win.dtype)
+    xp = jnp.concatenate([fc, win, fc], axis=1)       # (out_h+2, out_w+2)
+    acc = None
+    for dy in range(3):
+        for dx in range(3):
+            sl = xp[dy:dy + out_h, dx:dx + out_w]
+            acc = sl if acc is None else red(acc, sl)
+    return acc
 
 
 def _framediff_band(f0, f1, f2, *, threshold: int, maxval: int) -> jax.Array:
@@ -109,7 +129,7 @@ def _cascade_kernel(f0_ref, f1_ref, f2_ref, mask_ref, count_ref, fd, *,
         dil = stencil3x3(win, op="max", fill=0, out_h=bh + 2, out_w=Wp)
 
         # Eq. 6: 3x3 min, fill maxval — mask the pad region to maxval so
-        # the erode boundary matches the staged chain's fill bit-exactly
+        # the erode boundary matches the reference's fill bit-exactly
         g0 = (i - 1) * bh - 1                    # global row of dil row 0
         rows = g0 + jax.lax.broadcasted_iota(jnp.int32, (bh + 2, Wp), 0)
         cols = jax.lax.broadcasted_iota(jnp.int32, (bh + 2, Wp), 1)

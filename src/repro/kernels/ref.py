@@ -61,10 +61,11 @@ def pixel_cascade_ref(f0: jax.Array, f1: jax.Array, f2: jax.Array,
 
     The morphology runs as ``lax.reduce_window`` (bit-exact for integer
     max/min: window init 0 == dilate's fill since the mask is >= 0, init
-    ``maxval`` == erode's fill since the mask is <= maxval) — this is the
-    XLA-compiled fused twin the benchmarks time where compiled Pallas is
-    unavailable, so it should be the *fast* honest composition, not the
-    shift-and-mask teaching oracle above.
+    ``maxval`` == erode's fill since the mask is <= maxval).  This is
+    what ``ops.pixel_cascade(use_pallas=False)`` and
+    ``detect(use_pallas=False)`` run, the reference the kernel's callers
+    are compared against; ``dilate3x3_ref`` and ``erode3x3_ref`` above
+    are the shift-and-mask forms of the same two stencils.
     """
     m = framediff_ref(f0, f1, f2, threshold, maxval)
     win, strides = (1, 3, 3), (1, 1, 1)

@@ -3,9 +3,8 @@
 One pass over the whole fleet's (E, N) tick matrix produces route codes,
 escalation buffer slots (stable per-row prefix-sum compaction) and each
 row's escalated count, with an (E, 2) per-row runtime threshold matrix:
-every edge's triage and compaction in ONE launch per scheduler tick.  The
-one-edge entry points (``triage_dynamic_pallas``, ``triage_pallas``) are
-single-row launches of the same kernel.
+every edge's triage and compaction in ONE launch per scheduler tick.  A
+single edge's batch is a one-row matrix (``ops.triage_fleet``).
 
 Rows are independent, so the grid tiles them (``parallel``); each row's
 lanes are walked in ``LANE_BLOCK``-wide blocks (``arbitrary``: in order),
@@ -92,27 +91,3 @@ def triage_fleet_pallas(conf: jax.Array, thresholds: jax.Array, *,
         interpret=interpret,
     )(conf, thresholds)
     return routes, slots, counts[:, 0]
-
-
-def triage_dynamic_pallas(conf: jax.Array, thresholds: jax.Array, *,
-                          capacity: int, interpret: Optional[bool] = None):
-    """conf (N,) f32, thresholds (2,) f32 [alpha, beta] ->
-    (routes (N,) i32, slots (N,) i32, count (1,) i32).
-
-    Thresholds are runtime data, so Eqs. 8-9 moving them every tick never
-    retraces; this is the fleet kernel on a single row."""
-    routes, slots, count = triage_fleet_pallas(
-        conf[None], thresholds[None], capacity=capacity, interpret=interpret)
-    return routes[0], slots[0], count
-
-
-def triage_pallas(conf: jax.Array, *, alpha: float, beta: float,
-                  capacity: int, interpret: Optional[bool] = None):
-    """conf (N,) f32 -> (routes (N,) i32, slots (N,) i32, count (1,) i32).
-
-    Static-threshold convenience wrapper: packs alpha/beta into the
-    dynamic kernel's (2,) threshold input, so distinct thresholds share
-    one compilation."""
-    thresholds = jnp.asarray([alpha, beta], jnp.float32)
-    return triage_dynamic_pallas(conf, thresholds, capacity=capacity,
-                                 interpret=interpret)

@@ -148,7 +148,7 @@ class QueryReport:
     lan_bytes: int                         # shipped edge-to-edge
     escalated: int                         # items sent for re-classification
     rerouted: int                          # raw batches shed / failed-over
-    kernel_launches: int                   # batched triage_pallas calls
+    kernel_launches: int                   # fused triage_fleet launches
     ticks: int                             # scheduler intervals simulated
     queue_timeline: Dict[int, np.ndarray]  # node -> (ticks,) queue length
     per_node_busy: Dict[int, float]        # node -> total service seconds

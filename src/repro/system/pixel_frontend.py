@@ -17,8 +17,7 @@ tick at a time, with the fleet's frames kept on the device:
      connected components, the fixed box table, the crops and their patch
      tokens (``repro.detection.pipeline.detect``); only the counts, boxes,
      crops and tokens come back to the host, and the counts skip the
-     second program on motionless ticks.  ``fused=False`` keeps the
-     original staged three-launch chain as the differential reference.
+     second program on motionless ticks.
   3. classify — all of the tick's crops, across every camera, are scored
      by the CQ classifier in ONE bucket-padded jit launch
      (``kernels.ops.score_crops``) — launches per tick stay O(1) in fleet
@@ -123,7 +122,6 @@ class PixelFrontend(Frontend):
                  params=None, seed: int = 0,
                  query_class: int = SV.QUERY_CLASS,
                  threshold: int = 40, crop: int = 32, min_area: int = 12,
-                 use_pallas: bool = True, fused: bool = True,
                  cache: bool = True):
         super().__init__()
         assert crop % 8 == 0, "crop side must be patch-aligned (8 px)"
@@ -137,8 +135,6 @@ class PixelFrontend(Frontend):
         self.threshold = threshold
         self.crop = crop
         self.min_area = min_area
-        self.use_pallas = use_pallas
-        self.fused = fused           # ONE fused pixel launch vs staged three
         self.launches = 0            # classifier launches (one per tick)
         self._conf_fn = jax.jit(functools.partial(_conf_apply, self.cfg))
         self._cache_enabled = cache
@@ -200,8 +196,6 @@ class PixelFrontend(Frontend):
             with spans.span("detect", tick=k):
                 dets = DP.detect(frames, threshold=self.threshold,
                                  crop=self.crop, min_area=self.min_area,
-                                 use_pallas=self.use_pallas,
-                                 fused=self.fused,
                                  vocab_size=self.cfg.vocab_size,
                                  spans=spans, counters=counters)
 

@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 from scipy import ndimage
 
-from repro.detection import components, pipeline as DP
+from repro.detection import pipeline as DP
 from repro.kernels import ccl, ops
 from repro.system.pixel_frontend import COUNTERS
 
@@ -114,7 +114,7 @@ def test_kernel_labels_and_steps_match_jnp(kind, shape):
     np.testing.assert_array_equal(lab_k, lab_j)
     np.testing.assert_array_equal(steps_k, steps_j)
     for b in range(B):
-        lab_1, steps_1 = components.label_components(jnp.asarray(m[b:b + 1]))
+        lab_1, steps_1 = ccl.label_components(jnp.asarray(m[b:b + 1]))
         assert int(steps_1[0]) == int(steps_k[b])
         np.testing.assert_array_equal(np.asarray(lab_1[0]), lab_k[b])
         if int(steps_k[b]) < 256:            # converged: the true labels
@@ -135,7 +135,7 @@ def test_kernel_stops_at_the_cap_like_the_jnp_loop():
     same partial labels."""
     m = jnp.asarray(_fleet("full", (2, 40, 300)))
     lab_k, steps_k = ccl.label_components_pallas(m, max_iters=24)
-    lab_j, steps_j = components.label_components(m, max_iters=24)
+    lab_j, steps_j = ccl.label_components(m, max_iters=24)
     np.testing.assert_array_equal(np.asarray(lab_k), np.asarray(lab_j))
     assert np.asarray(steps_k).tolist() == np.asarray(steps_j).tolist() \
         == [ccl.STEPS, 24]
@@ -167,7 +167,7 @@ def test_kernel_or_jnp_by_frame_shape(monkeypatch):
     monkeypatch.setattr(ccl, "VMEM_BUDGET", 0)
     lab, steps = ops.label_components(m)
     assert not launches
-    want = components.label_components(m)
+    want = ccl.label_components(m)
     np.testing.assert_array_equal(np.asarray(lab), np.asarray(want[0]))
     np.testing.assert_array_equal(np.asarray(steps), np.asarray(want[1]))
 

@@ -5,6 +5,7 @@ import pytest
 
 from repro.data import synthetic_video as SV
 from repro.detection import components, pipeline
+from repro.kernels import ccl
 
 
 def test_motion_mask_finds_moving_object():
@@ -26,7 +27,7 @@ def test_label_components_two_blobs():
     m = np.zeros((1, 40, 40), np.int32)
     m[0, 2:8, 2:8] = 255
     m[0, 20:30, 25:35] = 255
-    lab = np.asarray(components.label_components(jnp.asarray(m))[0])
+    lab = np.asarray(ccl.label_components(jnp.asarray(m))[0])
     fg = lab[0][lab[0] >= 0]
     assert len(np.unique(fg)) == 2
 
@@ -38,7 +39,7 @@ def test_extract_boxes_filters_small_and_elongated():
     m[0, 5:20, 5:20] = 255      # big blob -> kept
     m[0, 30, 30] = 255          # single pixel -> dropped (min_area)
     m[0, 35, 2:30] = 255        # 1x28 line -> dropped (aspect)
-    lab, _ = components.label_components(jnp.asarray(m))
+    lab, _ = ccl.label_components(jnp.asarray(m))
     boxes, keep, comps = (np.asarray(a) for a in components.box_table(
         lab, 8, min_area=12, max_aspect=6.0))
     assert comps.tolist() == [3]

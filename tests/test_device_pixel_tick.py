@@ -18,7 +18,7 @@ import pytest
 
 from repro.data import synthetic_video as SV
 from repro.detection import pipeline as DP
-from repro.kernels import ops
+from repro.kernels import ccl, ops
 from repro.kernels import ref as KR
 from repro.system import PixelFrontend, pixel_city, run_query
 
@@ -183,7 +183,7 @@ def test_box_table_matches_reference_in_window_and_whole_frame(big):
     if big:
         m[1, 10:90, 100:120] = 255       # taller than the window
         m[2, 50:54, 20:240] = 255        # wider than the window
-    lab, _ = components.label_components(jnp.asarray(m))
+    lab, _ = ccl.label_components(jnp.asarray(m))
     boxes, keep, comps = (np.asarray(a) for a in jax.jit(
         functools.partial(components.box_table, max_boxes=32,
                           min_area=12))(lab))

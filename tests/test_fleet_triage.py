@@ -1,5 +1,5 @@
 """Fleet-scale engine tests: the fused (E, N) triage kernel vs E independent
-batched calls (hypothesis property), one-launch-per-tick on multi-edge
+per-edge references (hypothesis property), one-launch-per-tick on multi-edge
 fleets, per-edge threshold divergence under asymmetric load, and the
 city_scale smoke invariants."""
 import numpy as np
@@ -36,9 +36,8 @@ def test_triage_fleet_matches_per_edge_batched():
     routes, slots, counts = ops.triage_fleet(_pack(batches), th, capacity=4)
     routes, slots = np.asarray(routes), np.asarray(slots)
     for e, b in enumerate(batches):
-        rb, sb, cb = ops.triage_batched(
-            np.asarray(b, np.float32), alpha=float(th[e, 0]),
-            beta=float(th[e, 1]), capacity=4)
+        rb, sb, cb = ref.triage_ref(
+            np.asarray(b, np.float32), float(th[e, 0]), float(th[e, 1]), 4)
         np.testing.assert_array_equal(routes[e, :len(b)], np.asarray(rb))
         np.testing.assert_array_equal(slots[e, :len(b)], np.asarray(sb))
         assert int(np.asarray(counts)[e]) == int(cb)
@@ -111,9 +110,9 @@ def test_triage_fleet_property_matches_independent_calls():
                                  np.asarray(counts))
         for e, b in enumerate(batches):
             if b:
-                rb, sb, cb = ops.triage_batched(
-                    np.asarray(b, np.float32), alpha=float(th[e, 0]),
-                    beta=float(th[e, 1]), capacity=capacity)
+                rb, sb, cb = ref.triage_ref(
+                    np.asarray(b, np.float32), float(th[e, 0]),
+                    float(th[e, 1]), capacity)
                 np.testing.assert_array_equal(routes[e, :len(b)],
                                               np.asarray(rb))
                 np.testing.assert_array_equal(slots[e, :len(b)],

@@ -1,8 +1,8 @@
 """Fused pixel-cascade kernel: bit-exactness, launch budget, planar layout.
 
-The fused kernel's contract is strict equality: fused == staged (three
-separate Pallas launches) == the independent NumPy oracle, over every
-frame size / threshold / bucket-padding placement.  On top of that, the
+The fused kernel's contract is strict equality: kernel == the jnp
+reference (``use_pallas=False``) == the independent NumPy oracle, over
+every frame size / threshold / bucket-padding placement.  On top of that, the
 launch-budget acceptance — a pixel_city tick's whole framediff ->
 morphology -> score chain in <= 2 Pallas launches — is asserted with a
 monkeypatched launch counter.  The kernels are interpreted here (CPU);
@@ -24,6 +24,7 @@ from repro.kernels.buckets import (MAX_FRAME_ELEMS, MIN_FRAME_SIDE,
                                    validate_frame_hw)
 from repro.system.scenario import Scenario, pixel_city
 
+
 def _frames(rng, B, H, W):
     return rng.integers(0, 256, (3, B, H, W, 3)).astype(np.int32)
 
@@ -31,16 +32,16 @@ def _frames(rng, B, H, W):
 def _assert_cascade_exact(fs, threshold=40):
     f0, f1, f2 = (jnp.asarray(fs[i]) for i in range(3))
     mask_f, cnt_f = ops.pixel_cascade(f0, f1, f2, threshold=threshold)
-    mask_s, cnt_s = ops.pixel_cascade(f0, f1, f2, threshold=threshold,
-                                      fused=False)
+    mask_r, cnt_r = ops.pixel_cascade(f0, f1, f2, threshold=threshold,
+                                      use_pallas=False)
     mask_np, cnt_np = ref.pixel_cascade_np(fs[0], fs[1], fs[2], threshold)
-    np.testing.assert_array_equal(np.asarray(mask_f), np.asarray(mask_s))
+    np.testing.assert_array_equal(np.asarray(mask_f), np.asarray(mask_r))
     np.testing.assert_array_equal(np.asarray(mask_f), mask_np)
-    np.testing.assert_array_equal(np.asarray(cnt_f), np.asarray(cnt_s))
+    np.testing.assert_array_equal(np.asarray(cnt_f), np.asarray(cnt_r))
     np.testing.assert_array_equal(np.asarray(cnt_f), cnt_np)
 
 
-# --- bit-exactness: fused == staged == independent NumPy oracle --------------
+# --- bit-exactness: kernel == jnp reference == independent NumPy oracle ------
 
 
 def test_fused_matches_staged_and_oracle_fixed_shapes():
@@ -131,7 +132,7 @@ def test_planar_layout_matches_oracle():
 def test_pixel_tick_launch_budget(monkeypatch):
     """A pixel tick's framediff->morphology chain is ONE fused Pallas
     launch (<= 2 is the acceptance bar; score_crops is a jit'd model
-    apply, not a Pallas program), vs three on the staged path.
+    apply, not a Pallas program), and none on the jnp reference.
 
     Counted at trace time by monkeypatching ``pallas_call`` on the shared
     pallas module — so the frame shape must be FRESH (never traced in
@@ -157,14 +158,14 @@ def test_pixel_tick_launch_budget(monkeypatch):
     assert launches["n"] <= 2          # the acceptance bar
 
     launches["n"] = 0
-    ops.pixel_cascade(*f, threshold=41, fused=False)
-    assert launches["n"] == 3          # staged reference: 3 launches
+    ops.pixel_cascade(*f, threshold=41, use_pallas=False)
+    assert launches["n"] == 0          # the jnp reference: no launch
 
 
 def test_pixel_city_tick_detect_launch_budget(monkeypatch):
     """End-to-end: a pixel_city-style fleet tick through ``detect`` stays
-    within the <= 2 Pallas-launch budget on the fused path: the cascade
-    and the label fixpoint."""
+    within the <= 2 Pallas-launch budget: the cascade and the label
+    fixpoint."""
     launches = {"n": 0}
     real = pallas_mod.pallas_call
 
@@ -180,7 +181,7 @@ def test_pixel_city_tick_detect_launch_budget(monkeypatch):
     batch = rng.integers(0, 256, (3, 3, 61, 133, 3)).astype(np.int32)
     assert (cam.height, cam.width) == (96, 128)   # city preset sanity
     launches["n"] = 0
-    DP.detect(batch, threshold=40, fused=True)
+    DP.detect(batch, threshold=40)
     assert launches["n"] == 2
 
 
@@ -188,13 +189,14 @@ def test_pixel_city_tick_detect_launch_budget(monkeypatch):
 
 
 def test_detect_fused_matches_staged_end_to_end():
-    """Boxes and crops identical under fused and staged detection."""
+    """Boxes and crops identical under the kernels and the jnp
+    references (``use_pallas=False``)."""
     rng = np.random.default_rng(0)
     cam = SV.make_cameras(1, seed=11)[0]
     cam.base_rate, cam.busy_boost = 2.0, 0.0
     frames, _ = SV.render_triple(cam, 0.0, rng)
-    dets_f = DP.detect(frames, fused=True)[0]
-    dets_s = DP.detect(frames, fused=False)[0]
+    dets_f = DP.detect(frames)[0]
+    dets_s = DP.detect(frames, use_pallas=False)[0]
     assert len(dets_f) == len(dets_s) > 0
     for df, ds in zip(dets_f, dets_s):
         assert df.box == ds.box
@@ -215,12 +217,12 @@ def test_static_scene_skips_ccl(monkeypatch):
 
     monkeypatch.setattr(ops, "label_components", counting)
     static = np.full((2, 3, 96, 128, 3), 55, np.int32)
-    out = DP.detect(static, fused=True)
+    out = DP.detect(static)
     assert out == [[], []]
     assert called["n"] == 0
     moving = np.random.default_rng(3).integers(
         0, 256, (2, 3, 59, 127, 3)).astype(np.int32)
-    DP.detect(moving, fused=True)
+    DP.detect(moving)
     assert called["n"] == 1
 
 

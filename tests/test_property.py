@@ -17,7 +17,7 @@ from hypothesis import given, settings, strategies as st
 from repro.core import cascade as C
 from repro.core import latency as LT
 from repro.core.thresholds import ThresholdState
-from repro.kernels import ops, ref
+from repro.kernels import ref
 
 fin = dict(allow_nan=False, allow_infinity=False)
 
@@ -65,13 +65,13 @@ def test_triage_compaction_properties(n, a, b, cap, seed):
 def test_morphology_order_properties(b, h, w, seed):
     x = (jax.random.uniform(jax.random.PRNGKey(seed), (b, h, w)) > 0.6
          ).astype(jnp.int32) * 255
-    d = ops.dilate3x3(x, use_pallas=False)
-    e = ops.erode3x3(x, use_pallas=False)
+    d = ref.dilate3x3_ref(x)
+    e = ref.erode3x3_ref(x)
     # extensivity / anti-extensivity
     assert bool(jnp.all(d >= x))
     assert bool(jnp.all(e <= x))
     # duality on binary masks: erode(x) == 255 - dilate(255 - x)
-    dual = 255 - np.asarray(ops.dilate3x3(255 - x, use_pallas=False))
+    dual = 255 - np.asarray(ref.dilate3x3_ref(255 - x))
     np.testing.assert_array_equal(np.asarray(e), dual)
 
 
@@ -81,7 +81,7 @@ def test_framediff_static_scene_is_silent(seed, thresh):
     """No motion => empty mask regardless of threshold (property: the
     detector never hallucinates on identical frames)."""
     f = jax.random.randint(jax.random.PRNGKey(seed), (1, 32, 128, 3), 0, 256)
-    mask = ops.framediff(f, f, f, threshold=thresh, use_pallas=False)
+    mask = ref.framediff_ref(f, f, f, thresh)
     assert int(jnp.sum(mask)) == 0
 
 

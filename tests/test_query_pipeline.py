@@ -111,14 +111,19 @@ def test_edge_estimator_unbiased_by_reclassify_mix():
     assert est.t > 0.6 * sc.edge_service_s
 
 
-# --- batched triage: capacity overflow ---------------------------------------
+# --- one edge's triage (a (1, N) fleet row): capacity overflow -------------
+
+
+def _triage_row(conf, alpha, beta, capacity):
+    routes, slots, counts = ops.triage_fleet(
+        np.asarray(conf, np.float32)[None], [[alpha, beta]],
+        capacity=capacity)
+    return routes[0], slots[0], counts[0]
 
 
 def test_triage_batched_overflow_leaves_tail_unescalated():
     conf = np.full(20, 0.5, np.float32)           # all in the [beta,alpha] band
-    routes, slots, count = ops.triage_batched(
-        conf, alpha=0.8, beta=0.1, capacity=4)
-    routes, slots = np.asarray(routes), np.asarray(slots)
+    routes, slots, count = _triage_row(conf, 0.8, 0.1, 4)
     assert int(count) == 20                       # count reports all escalated
     np.testing.assert_array_equal(slots[:4], [0, 1, 2, 3])
     assert np.all(slots[4:] == -1)                # overflow: no buffer slot
@@ -129,7 +134,7 @@ def test_triage_batched_overflow_leaves_tail_unescalated():
 def test_triage_batched_matches_ref_under_overflow(n, cap):
     rng = np.random.default_rng(n)
     conf = rng.uniform(0, 1, n).astype(np.float32)
-    got = ops.triage_batched(conf, alpha=0.7, beta=0.2, capacity=cap)
+    got = _triage_row(conf, 0.7, 0.2, cap)
     want = ref.triage_ref(conf, 0.7, 0.2, cap)
     for g, w in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
@@ -140,7 +145,7 @@ def test_triage_batched_thresholds_are_runtime_data():
     (and hits the same cached jit trace — no per-threshold recompiles)."""
     conf = np.linspace(0, 1, 33, dtype=np.float32)
     for a, b in [(0.9, 0.05), (0.8, 0.1), (0.55, 0.3), (0.7, 0.2)]:
-        got = ops.triage_batched(conf, alpha=a, beta=b, capacity=16)
+        got = _triage_row(conf, a, b, 16)
         want = ref.triage_ref(conf, a, b, 16)
         for g, w in zip(got, want):
             np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
